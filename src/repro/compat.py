@@ -1,50 +1,33 @@
-"""jax version compatibility shims.
+"""One home for mesh and shard_map construction.
 
-The codebase targets the current jax API (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``); older releases (<=0.4.x) ship the
-same functionality under ``jax.experimental.shard_map`` with ``check_rep`` /
-``auto`` instead of ``check_vma`` / ``axis_names``.  Everything that builds a
-mesh or a shard_map goes through this module so one import works everywhere.
+Everything that builds a mesh or a shard_map goes through this module, so
+the mesh axis types and the shard_map checking mode are chosen in one place
+(written against JAX 0.9).
 """
 from __future__ import annotations
 
 import jax
 
-_HAS_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-_HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with Auto axis types where supported."""
+    """``jax.make_mesh`` with Auto axis types."""
     kw = {"devices": devices} if devices is not None else {}
-    if _HAS_AXIS_TYPES:
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axis_names)
-    return jax.make_mesh(axis_shapes, axis_names, **kw)
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names), **kw)
 
 
 def set_mesh(mesh):
-    """``jax.set_mesh`` context; on old jax the Mesh itself is the context
-    manager that installs it as the ambient mesh."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """``jax.set_mesh`` context: installs ``mesh`` as the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False,
               axis_names=None):
-    """``jax.shard_map`` (new) / ``jax.experimental.shard_map`` (old).
-
-    ``axis_names``: mesh axes the body is manual over (all if None); on old
-    jax this is translated to the complementary ``auto`` set.
-    """
-    if _HAS_NEW_SHARD_MAP:
-        kw = {"check_vma": check_vma}
-        if axis_names is not None:
-            kw["axis_names"] = frozenset(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    kw = {"check_rep": check_vma}
+    """``jax.shard_map``; ``axis_names``: mesh axes the body is manual over
+    (all if None)."""
+    kw = {"check_vma": check_vma}
     if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+        kw["axis_names"] = frozenset(axis_names)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)

@@ -157,6 +157,11 @@ class AOTGraphEngine:
     def num_graphs(self) -> int:
         return len(self._cache)
 
+    def executable(self, key: tuple):
+        """The compiled executable cached for ``key`` (no lookup counted) —
+        for inspecting what was compiled, e.g. its HLO text."""
+        return self._cache[key]
+
     def cached_keys(self) -> list:
         """The captured bucket keys (elastic-join pre-warm enumerates these
         to compile their wider-ring variants off the hot path)."""

@@ -3,7 +3,9 @@
 Forward: blockwise online-softmax, grid (B, H, q blocks, kv blocks), f32
 accumulators in VMEM scratch, GQA handled by indexing the kv head h*Hkv//Hq
 (no materialised head expansion).  Fully-masked causal blocks skip their
-FLOPs via @pl.when.
+FLOPs via @pl.when.  The kernel reads head-major [B, H, S, D] copies of
+q/k/v, so every block's last two dims are (rows, D) — Mosaic's
+(8, 128)-or-full-dim rule then holds for any head count.
 
 Backward: flash-style *scanned jnp* backward (no S^2 materialisation) wired
 through ``jax.custom_vjp`` — forward runs the kernel, backward recomputes
@@ -48,9 +50,9 @@ def _fwd_kernel(kv_len_ref,
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale   # [bq, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # [bk, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)            # [bk, D]
+        q = q_ref[0, 0].astype(jnp.float32) * scale          # [bq, D]
+        k = k_ref[0, 0].astype(jnp.float32)                  # [bk, D]
+        v = v_ref[0, 0].astype(jnp.float32)                  # [bk, D]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # [bq, bk]
         cpos = ik * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -74,8 +76,8 @@ def _fwd_kernel(kv_len_ref,
     @pl.when(ik == nk - 1)
     def _finalize():
         l = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_scr[:, :1] + jnp.log(l))[:, 0]
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0, 0] = (m_scr[:, :1] + jnp.log(l))[:, 0]
 
 
 def _flash_fwd(q, k, v, scale, causal, q_offset, kv_len, interpret,
@@ -94,19 +96,20 @@ def _flash_fwd(q, k, v, scale, causal, q_offset, kv_len, interpret,
         _fwd_kernel, scale=scale, causal=causal, q_offset=q_offset,
         bq=bq, bk=bk, nk=nk)
 
+    def kv_map(b, h, iq, ik, kl):
+        return (b, h * Hkv // Hq, ik, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, Hq, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, iq, ik, kl: (b, iq, h, 0)),
-            pl.BlockSpec((1, bk, 1, D),
-                         lambda b, h, iq, ik, kl: (b, ik, h * Hkv // Hq, 0)),
-            pl.BlockSpec((1, bk, 1, Dv),
-                         lambda b, h, iq, ik, kl: (b, ik, h * Hkv // Hq, 0)),
+            pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik, kl: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, bk, D), kv_map),
+            pl.BlockSpec((1, 1, bk, Dv), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, 1, Dv), lambda b, h, iq, ik, kl: (b, iq, h, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, iq, ik, kl: (b, h, iq)),
+            pl.BlockSpec((1, 1, bq, Dv), lambda b, h, iq, ik, kl: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, 1, bq), lambda b, h, iq, ik, kl: (b, h, 0, iq)),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 128), jnp.float32),
@@ -114,16 +117,18 @@ def _flash_fwd(q, k, v, scale, causal, q_offset, kv_len, interpret,
             pltpu.VMEM((bq, Dv), jnp.float32),
         ],
     )
+    head_major = (0, 2, 1, 3)                   # [B, S, H, D] <-> [B, H, S, D]
     out, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, Sq, Hq, Dv), q.dtype),
-            jax.ShapeDtypeStruct((B, Hq, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hq, Sq, Dv), q.dtype),
+            jax.ShapeDtypeStruct((B, Hq, 1, Sq), jnp.float32),
         ],
         interpret=interpret,
-    )(kv_len, q, k, v)
-    return out, lse
+    )(kv_len, q.transpose(head_major), k.transpose(head_major),
+      v.transpose(head_major))
+    return out.transpose(head_major), lse.reshape(B, Hq, Sq)
 
 
 # --------------------------------------------------------------------------- #
@@ -212,7 +217,8 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
     """Kernel-path flash attention; see ``ref.flash_attention`` for semantics.
 
     q [B, Sq, Hq, D]; k/v [B, Skv, Hkv, D(v)]; Sq/Skv must divide into the
-    128-element q/kv blocks (callers pad); bf16 or f32 in, f32 accumulation,
+    128-element q/kv blocks (``ops.flash_attention`` pads longer sequences
+    and masks the pad with ``kv_len``); bf16 or f32 in, f32 accumulation,
     out in q.dtype + lse [B, Hq, Sq] f32.  KV pools are never quantized on
     this path — prefill reads/writes full-precision activations; quantization
     happens when pages enter the paged pool (``core/migrate.py``).  Pinned by

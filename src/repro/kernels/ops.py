@@ -52,9 +52,35 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
         return ref.flash_attention(q, k, v, causal=causal, scale=scale,
                                    q_offset=q_offset, kv_len=kv_len)
     from . import flash_attention as fa
-    return fa.flash_attention(q, k, v, causal=causal, scale=scale,
-                              q_offset=q_offset, kv_len=kv_len,
-                              interpret=(impl == "pallas_interpret"))
+    # the kernel tiles sequences longer than one block into whole blocks:
+    # pad q and kv up to a block multiple, hide the kv pad behind kv_len,
+    # and drop the padded query rows from the result
+    B, Sq, Skv = q.shape[0], q.shape[1], k.shape[1]
+    pad_q = _pad_to_blocks(Sq, fa.DEFAULT_BQ) - Sq
+    pad_kv = _pad_to_blocks(Skv, fa.DEFAULT_BK) - Skv
+    if pad_kv:
+        if kv_len is None:
+            kv_len = jnp.full((B,), Skv, jnp.int32)
+        k = _pad_seq(k, pad_kv)
+        v = _pad_seq(v, pad_kv)
+    out, lse = fa.flash_attention(_pad_seq(q, pad_q), k, v, causal=causal,
+                                  scale=scale, q_offset=q_offset,
+                                  kv_len=kv_len,
+                                  interpret=(impl == "pallas_interpret"))
+    return out[:, :Sq], lse[:, :, :Sq]
+
+
+def _pad_to_blocks(n: int, block: int) -> int:
+    """Sequence length the flash kernel can tile: a single block of any
+    size, else a whole number of ``block``-row blocks."""
+    return n if n <= block else -(-n // block) * block
+
+
+def _pad_seq(x, pad: int):
+    """Zero-pad axis 1 (sequence) of [B, S, H, D] by ``pad`` rows."""
+    if not pad:
+        return x
+    return jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
 
 
 def attention(q, k, v, **kw):

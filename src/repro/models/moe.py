@@ -98,19 +98,22 @@ def moe_ffn_batched(cfg: ModelConfig, p: dict, x: jax.Array,
 
     Long sequences scan over ``chunk``-token slices so the dispatch/combine
     buffers peak at ONE chunk (the full-sequence buffers dominated prefill
-    memory: ~9 GB/layer at 32k before chunking)."""
+    memory: ~9 GB/layer at 32k before chunking); a remainder shorter than a
+    chunk is its own last slice."""
     B, S, D = x.shape
+
+    def rows(xs):
+        return jax.vmap(lambda row: moe_ffn(cfg, p, row))(xs)
+
     if S <= chunk:
-        return jax.vmap(lambda row: moe_ffn(cfg, p, row))(x)
-    assert S % chunk == 0, (S, chunk)
-    nch = S // chunk
-    xc = x.reshape(B, nch, chunk, D).transpose(1, 0, 2, 3)   # [nch, B, c, D]
-
-    def body(_, xs):
-        return None, jax.vmap(lambda row: moe_ffn(cfg, p, row))(xs)
-
-    _, out = jax.lax.scan(body, None, xc)
-    return out.transpose(1, 0, 2, 3).reshape(B, S, D)
+        return rows(x)
+    nch, tail = divmod(S, chunk)
+    xc = x[:, :nch * chunk].reshape(B, nch, chunk, D).transpose(1, 0, 2, 3)
+    _, out = jax.lax.scan(lambda _, xs: (None, rows(xs)), None, xc)
+    out = out.transpose(1, 0, 2, 3).reshape(B, nch * chunk, D)
+    if tail:
+        out = jnp.concatenate([out, rows(x[:, nch * chunk:])], axis=1)
+    return out
 
 
 def aux_load_balance_loss(cfg: ModelConfig, router_w: jax.Array, x: jax.Array):

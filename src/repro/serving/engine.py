@@ -242,17 +242,20 @@ class NanoCPEngine:
         # them (implicit device-to-device transfers on multi-device meshes —
         # caught by the conformance matrix's transfer-guard window) and the
         # first donation silently degrades to copy-on-donate.
+        # The layout conversion runs eagerly, not under jit: leaves it passes
+        # through unchanged (expert weights, embeddings, norms) stay the
+        # caller's buffers instead of a second copy — at published widths
+        # the experts are most of the device memory.
         from jax.sharding import NamedSharding
         if self.is_encdec:
-            self.decode_params = jax.jit(
-                lambda p: dcp.to_encdec_decode_params(cfg, p, self.tp))(params)
+            self.decode_params = dcp.to_encdec_decode_params(cfg, params,
+                                                             self.tp)
             self.state = dcp.init_encdec_serve_state(
                 cfg, self._dims0, num_instances, dtype=jnp.float32)
             pspecs = dcp.encdec_param_specs(cfg, self.decode_params)
             sspecs = dcp.encdec_state_specs(self.state)
         else:
-            self.decode_params = jax.jit(
-                lambda p: dcp.to_decode_params(cfg, p, self.tp))(params)
+            self.decode_params = dcp.to_decode_params(cfg, params, self.tp)
             self.state = dcp.init_serve_state(cfg, self._dims0, num_instances,
                                               dtype=jnp.float32)
             pspecs = dcp.decode_param_specs(cfg, self.decode_params)

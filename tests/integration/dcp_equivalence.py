@@ -13,6 +13,12 @@ from repro.core.state import ClusterState, Request
 from repro.core.scheduler import DualBalancedScheduler
 from repro.core.bucketing import CPBuckets, ShapeBuckets
 
+# max |logit - ref| / max |ref|, all in f32 on the CPU: the decode path
+# measures <= 1e-5 here, while one page of KV read from the wrong place
+# measures >= 1e-2.  Token equality is checked wherever the reference's
+# top-2 margin is above the same bound.
+LOGIT_RTOL = 1e-4
+
 
 def run_equiv(arch, backend="routed", steps=4, seed=0, I=4, TP=2):
     over = {}
@@ -31,9 +37,13 @@ def run_equiv(arch, backend="routed", steps=4, seed=0, I=4, TP=2):
                            kv_stripes=_ps)
     is_ssm_family = cfg.family in ("ssm", "hybrid")
     buckets = CPBuckets(edges=(100, 256), degrees=(1, 2, 3))
+    # this harness is the data plane: it loads KV once and never applies
+    # a plan's KV moves, so the scheduler must not escalate or relax
+    # (escalation/relaxation have their own conformance cells)
     sched = DualBalancedScheduler(buckets=buckets,
                                   allow_rebalance=not is_ssm_family,
-                                  has_kv=cfg.has_attention)
+                                  has_kv=cfg.has_attention,
+                                  allow_escalation=False)
     prompts = {0: 50, 1: 130, 2: 40, 3: 260, 4: 64}
     rng_np = np.random.default_rng(seed)
     prompt_tokens = {r: rng_np.integers(0, cfg.vocab_size, (L,))
@@ -84,6 +94,7 @@ def run_equiv(arch, backend="routed", steps=4, seed=0, I=4, TP=2):
     shape_buckets = ShapeBuckets(m_buckets=(8,) if is_ssm_family else (1,2,4,8), s_buckets=(0,1,2,4,8), window=W)
     for t in range(steps):
         plan = sched.schedule(cluster)
+        assert not (plan.escalations or plan.relaxations or plan.copies), plan
         tbl = routing.lower_plan(cluster, plan, buckets=shape_buckets,
                                  append_tokens=cfg.has_attention,
                                  next_tokens=next_tok)
@@ -106,11 +117,15 @@ def run_equiv(arch, backend="routed", steps=4, seed=0, I=4, TP=2):
             ref_last = np.asarray(ref_logits[0, -1], np.float32)
             i, b = cluster.slot_map[r]
             got = logits[i, b]
-            err = np.max(np.abs(got - ref_last)) / (np.max(np.abs(ref_last)) + 1e-9)
+            scale = np.max(np.abs(ref_last)) + 1e-9
+            err = np.max(np.abs(got - ref_last)) / scale
             max_err = max(max_err, err)
+            assert err <= LOGIT_RTOL, (arch, t, r, err)
             tok_ref = int(np.argmax(ref_last))
-            assert int(toks[i, b]) == tok_ref, \
-                (arch, t, r, int(toks[i, b]), tok_ref, err)
+            top2 = np.sort(ref_last)[-2:]
+            if top2[1] - top2[0] > LOGIT_RTOL * scale:   # not a near-tie
+                assert int(toks[i, b]) == tok_ref, \
+                    (arch, t, r, int(toks[i, b]), tok_ref, err)
             gen_ref[r].append(tok_ref)
             next_tok[r] = tok_ref
         for r in list(cluster.active):

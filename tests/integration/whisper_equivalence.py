@@ -12,6 +12,10 @@ from repro.core.state import ClusterState, Request
 from repro.core.scheduler import DualBalancedScheduler
 from repro.core.bucketing import CPBuckets, ShapeBuckets
 
+# max |logit - ref| / max |ref| in f32 on the CPU: measured <= 1e-6 here; a
+# cross-KV page read from the wrong place measures >= 1e-2
+LOGIT_RTOL = 1e-4
+
 cfg = reduced(CONFIGS["whisper-base"], vocab_size=256)
 rng = jax.random.PRNGKey(0)
 params = jax.tree.map(lambda x: x.astype(jnp.float32) if x.dtype == jnp.bfloat16 else x,
@@ -20,7 +24,10 @@ params = jax.tree.map(lambda x: x.astype(jnp.float32) if x.dtype == jnp.bfloat16
 I, W, PAGE, TP, STEPS = 4, 4, 16, 2, 4
 cluster = ClusterState(num_instances=I, instances_per_node=W,
                        kv_capacity_tokens=2048, page_size=PAGE)
-sched = DualBalancedScheduler(buckets=CPBuckets(edges=(100, 256), degrees=(1, 2, 3)))
+# cross pools are read-only and this harness never applies a plan's KV
+# moves: no escalation (and so no relaxation), as in the engine
+sched = DualBalancedScheduler(buckets=CPBuckets(edges=(100, 256), degrees=(1, 2, 3)),
+                              allow_escalation=False)
 # (enc frames, decoder prefix tokens)
 reqs = {0: (80, 3), 1: (300, 5), 2: (150, 2), 3: (48, 4)}
 rng_np = np.random.default_rng(0)
@@ -69,6 +76,7 @@ step_fn, d_key = None, None
 sb = ShapeBuckets(m_buckets=(1, 2), s_buckets=(1, 2), window=W)
 for t in range(STEPS):
     plan = sched.schedule(cluster)
+    assert not (plan.escalations or plan.relaxations or plan.copies), plan
     tbl = routing.lower_plan(cluster, plan, buckets=sb, append_tokens=False,
                              next_tokens=next_tok)
     tbl_dev = routing.as_device_arrays(tbl)
@@ -89,10 +97,14 @@ for t in range(STEPS):
                                               enc_states[r])
         ref_last = np.asarray(ref_logits[0, -1], np.float32)
         i, b = cluster.slot_map[r]
-        err = np.max(np.abs(logits[i, b] - ref_last)) / (np.max(np.abs(ref_last)) + 1e-9)
+        scale = np.max(np.abs(ref_last)) + 1e-9
+        err = np.max(np.abs(logits[i, b] - ref_last)) / scale
         maxe = max(maxe, err)
+        assert err <= LOGIT_RTOL, (t, r, err)
         tok_ref = int(np.argmax(ref_last))
-        assert int(toks[i, b]) == tok_ref, (t, r, int(toks[i, b]), tok_ref, err)
+        top2 = np.sort(ref_last)[-2:]
+        if top2[1] - top2[0] > LOGIT_RTOL * scale:     # not a near-tie
+            assert int(toks[i, b]) == tok_ref, (t, r, int(toks[i, b]), tok_ref, err)
         gen[r].append(tok_ref)
         next_tok[r] = tok_ref
     for r in list(cluster.active):

@@ -83,6 +83,25 @@ def test_flash_vs_oracle(rng, B, Sq, Skv, Hq, Hkv, D, causal, dtype):
     np.testing.assert_allclose(np.asarray(l_k), np.asarray(l_r), atol=1e-3)
 
 
+@pytest.mark.parametrize("S,with_kv_len", [(37, False), (300, False),
+                                           (300, True)])
+def test_ops_flash_pads_any_length(rng, monkeypatch, S, with_kv_len):
+    """Prompt lengths that are not a block multiple: ``ops.flash_attention``
+    pads q/kv to whole 128-row blocks, masks the kv pad and slices the
+    result back (GQA, causal prefill)."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "FORCE_IMPL", "pallas_interpret")
+    q = jnp.asarray(rng.standard_normal((1, S, 8, 128)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((1, S, 2, 128)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((1, S, 2, 128)), jnp.float32)
+    kv_len = jnp.asarray([S - 5], jnp.int32) if with_kv_len else None
+    o_r, l_r = ref.flash_attention(q, k, v, causal=True, kv_len=kv_len)
+    o_k, l_k = ops.flash_attention(q, k, v, causal=True, kv_len=kv_len)
+    assert o_k.shape == o_r.shape and l_k.shape == l_r.shape
+    np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_r), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(l_k), np.asarray(l_r), atol=1e-3)
+
+
 def test_flash_mla_dv_neq_dk(rng):
     """MLA train shape: Dk=96 (nope+rope), Dv=64."""
     q = jnp.asarray(rng.standard_normal((1, 128, 4, 96)), jnp.float32)

@@ -76,14 +76,15 @@ def test_ssm_decode_continues_prefill(rng):
                                atol=1e-4)
 
 
-def test_moe_chunked_matches_unchunked(rng):
+@pytest.mark.parametrize("chunk", [32, 24])   # 24: a 16-token remainder
+def test_moe_chunked_matches_unchunked(rng, chunk):
     from repro.models import moe
     cfg = reduced(CONFIGS["phi3.5-moe-42b-a6.6b"], capacity_factor=8.0)
     p = moe.make_moe_params(jax.random.PRNGKey(1), cfg)
     x = jnp.asarray(rng.standard_normal((2, 64, cfg.d_model)), jnp.float32)
     p = jax.tree.map(lambda v: v.astype(jnp.float32), p)
     full = moe.moe_ffn_batched(cfg, p, x, chunk=64)
-    chunked = moe.moe_ffn_batched(cfg, p, x, chunk=32)
+    chunked = moe.moe_ffn_batched(cfg, p, x, chunk=chunk)
     np.testing.assert_allclose(np.asarray(full), np.asarray(chunked),
                                atol=1e-4)
 
