@@ -1,0 +1,6 @@
+"""Median over requests of each request's mean gap between its tokens,
+on the client's clock (``stats.py``)."""
+
+
+def read(run):
+    return run["e2e"].get("tpot_p50_ms")
