@@ -683,8 +683,13 @@ def build_decode_step(cfg: ModelConfig, dims: DecodeDims):
             bp = jax.tree.map(
                 lambda w: w.astype(jnp.bfloat16)
                 if w.dtype == jnp.float8_e4m3fn else w, bp)
-            blk = {k: jax.lax.dynamic_index_in_dim(v, i, 0, keepdims=False)
-                   for k, v in st.items()}
+            # named scopes (``pool_carry``, ``attention``, ``ffn``,
+            # ``head``) tag each op's metadata, so a device trace can sum
+            # the step's time by part; they change nothing XLA computes
+            with jax.named_scope("pool_carry"):
+                blk = {k: jax.lax.dynamic_index_in_dim(v, i, 0,
+                                                       keepdims=False)
+                       for k, v in st.items()}
             ai = si = 0
             upd = {}
             for li, kind in enumerate(pattern):
@@ -692,37 +697,44 @@ def build_decode_step(cfg: ModelConfig, dims: DecodeDims):
                 if kind["mixer"] == "attn":
                     # per-device sub-pool: [ai, I=0, tp=0, F', page, dk]
                     # (scale sidecars [ai, I=0, tp=0, F'] when quantized)
-                    if cfg.is_mla:
-                        pools = (blk["kv_pool"][ai, 0, 0], None,
-                                 blk["kv_scale"][ai, 0, 0] if quantized
-                                 else None, None)
-                    else:
-                        pools = (blk["k_pool"][ai, 0, 0],
-                                 blk["v_pool"][ai, 0, 0],
-                                 blk["k_scale"][ai, 0, 0] if quantized
-                                 else None,
-                                 blk["v_scale"][ai, 0, 0] if quantized
-                                 else None)
-                    mix, pools_out = _attn_layer(cfg, dims, lp, x, pos,
-                                                 pools, tbl, hl, geom)
-                    if cfg.is_mla:
-                        upd.setdefault("kv_pool", []).append(
-                            pools_out[0][None])
-                        if quantized:
-                            upd.setdefault("kv_scale", []).append(
-                                pools_out[2][None])
-                    else:
-                        upd.setdefault("k_pool", []).append(pools_out[0][None])
-                        upd.setdefault("v_pool", []).append(pools_out[1][None])
-                        if quantized:
-                            upd.setdefault("k_scale", []).append(
-                                pools_out[2][None])
-                            upd.setdefault("v_scale", []).append(
-                                pools_out[3][None])
+                    with jax.named_scope("pool_carry"):
+                        if cfg.is_mla:
+                            pools = (blk["kv_pool"][ai, 0, 0], None,
+                                     blk["kv_scale"][ai, 0, 0] if quantized
+                                     else None, None)
+                        else:
+                            pools = (blk["k_pool"][ai, 0, 0],
+                                     blk["v_pool"][ai, 0, 0],
+                                     blk["k_scale"][ai, 0, 0] if quantized
+                                     else None,
+                                     blk["v_scale"][ai, 0, 0] if quantized
+                                     else None)
+                    with jax.named_scope("attention"):
+                        mix, pools_out = _attn_layer(cfg, dims, lp, x, pos,
+                                                     pools, tbl, hl, geom)
+                    with jax.named_scope("pool_carry"):
+                        if cfg.is_mla:
+                            upd.setdefault("kv_pool", []).append(
+                                pools_out[0][None])
+                            if quantized:
+                                upd.setdefault("kv_scale", []).append(
+                                    pools_out[2][None])
+                        else:
+                            upd.setdefault("k_pool", []).append(
+                                pools_out[0][None])
+                            upd.setdefault("v_pool", []).append(
+                                pools_out[1][None])
+                            if quantized:
+                                upd.setdefault("k_scale", []).append(
+                                    pools_out[2][None])
+                                upd.setdefault("v_scale", []).append(
+                                    pools_out[3][None])
                     ai += 1
                 else:
-                    sstate = (blk["conv_x"][si, 0], blk["conv_B"][si, 0],
-                              blk["conv_C"][si, 0], blk["ssm_state"][si, 0])
+                    with jax.named_scope("pool_carry"):
+                        sstate = (blk["conv_x"][si, 0], blk["conv_B"][si, 0],
+                                  blk["conv_C"][si, 0],
+                                  blk["ssm_state"][si, 0])
                     mix, s_out = _ssm_layer(cfg, dims, lp, x, sstate)
                     for nm, vv in zip(("conv_x", "conv_B", "conv_C",
                                        "ssm_state"), s_out):
@@ -731,32 +743,35 @@ def build_decode_step(cfg: ModelConfig, dims: DecodeDims):
                 x = x + mix
                 if kind["ffn"] != "none":
                     h = L.apply_norm(cfg, lp["ln2"], x)
-                    if kind["ffn"] == "moe":
-                        f = moe_decode_ffn(cfg, lp["ffn"], h,
-                                           axis=dims.data,
-                                           axis_size=dims.data_size,
-                                           tp_axis=dims.model)
-                    else:
-                        f = dense_decode_ffn(cfg, lp["ffn"], h,
-                                             tp_axis=dims.model)
+                    with jax.named_scope("ffn"):
+                        if kind["ffn"] == "moe":
+                            f = moe_decode_ffn(cfg, lp["ffn"], h,
+                                               axis=dims.data,
+                                               axis_size=dims.data_size,
+                                               tp_axis=dims.model)
+                        else:
+                            f = dense_decode_ffn(cfg, lp["ffn"], h,
+                                                 tp_axis=dims.model)
                     x = x + f
-            blk_new = {k: jnp.stack(v)[:, None] for k, v in upd.items()}
-            st = {k: jax.lax.dynamic_update_index_in_dim(st[k], blk_new[k], i, 0)
-                  for k in st}
+            with jax.named_scope("pool_carry"):
+                blk_new = {k: jnp.stack(v)[:, None] for k, v in upd.items()}
+                st = {k: jax.lax.dynamic_update_index_in_dim(
+                    st[k], blk_new[k], i, 0) for k in st}
             return (x, st), None
 
         nb = cfg.num_blocks
         xs = {"params": params["blocks"], "idx": jnp.arange(nb)}
         (x, new_pools), _ = jax.lax.scan(block_fn, (x, state), xs)
 
-        x = L.apply_norm(cfg, params["final_norm"], x)
-        if cfg.tie_embeddings:
-            logits = x @ params["embed"]["tok"].T
-        else:
-            logits = x @ params["head"]["w"]
-        logits = logits.astype(jnp.float32)
-        nxt = _sample_greedy(logits, vs_local, dims.model)
-        nxt = jnp.where(tbl["slot_active"][0].astype(bool), nxt, -1)
+        with jax.named_scope("head"):
+            x = L.apply_norm(cfg, params["final_norm"], x)
+            if cfg.tie_embeddings:
+                logits = x @ params["embed"]["tok"].T
+            else:
+                logits = x @ params["head"]["w"]
+            logits = logits.astype(jnp.float32)
+            nxt = _sample_greedy(logits, vs_local, dims.model)
+            nxt = jnp.where(tbl["slot_active"][0].astype(bool), nxt, -1)
         return new_pools, nxt[None, :], logits[None]
 
     return step

@@ -114,6 +114,33 @@ class _Inflight:
     logits: object = None
 
 
+class _Span:
+    """One phase of the engine: a ``jax.profiler.TraceAnnotation`` named
+    ``nanocp.<name>`` (on the profiler's clock, beside the device's ops,
+    when a trace is being taken; about a microsecond when none is) and its
+    own ``perf_counter`` duration added to ``engine.timings["<name>_us"]``
+    (dots become underscores: ``prefill.forward`` -> ``prefill_forward_us``;
+    a phase entered twice in one step sums)."""
+    __slots__ = ("eng", "key", "ann", "t0")
+
+    def __init__(self, eng, name: str, args: dict):
+        self.eng = eng
+        self.key = name.replace(".", "_") + "_us"
+        self.ann = jax.profiler.TraceAnnotation("nanocp." + name, **args)
+
+    def __enter__(self):
+        self.ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        us = (time.perf_counter() - self.t0) * 1e6
+        self.ann.__exit__(*exc)
+        timings = self.eng.timings
+        timings[self.key] = timings.get(self.key, 0.0) + us
+        return False
+
+
 class NanoCPEngine:
     def __init__(self, cfg: ModelConfig, params, mesh, *,
                  num_instances: int, instances_per_node: int,
@@ -298,7 +325,9 @@ class NanoCPEngine:
         self.iterations = 0
         self._inflight: _Inflight | None = None
         self._t0 = time.monotonic()
-        # hot-path introspection (benchmarks/decode_step.py, tests)
+        # the last step's phases, "<span name>_us" -> microseconds (see
+        # ``span``); read by the chip benchmark's per-layer metrics
+        # (benchmarks/chip/metrics), benchmarks/decode_step.py and the tests
         self.timings: dict = {}
         self.last_bucket: tuple | None = None
         # lowered rotation rounds of the last dispatched step
@@ -314,11 +343,17 @@ class NanoCPEngine:
             "degraded_finishes": 0, "joins": 0,
             "rejected": 0, "shed": 0, "preemptions": 0,
             # PR 8: global prefix cache + refcounted frame ownership
-            "prefix_hit_tokens": 0, "prefix_inserts": 0,
-            "copy_tokens": 0, "forks": 0,
+            "prefix_hit_tokens": 0, "prefix_inserts": 0, "forks": 0,
             # PR 9: disaggregated prefill cells + streamed KV handoff
             "staged": 0, "prefill_chunks": 0, "handoff_tokens": 0}
         self._donation_ptrs = None
+
+    def span(self, name: str, **args) -> _Span:
+        """``with self.span("lower"):`` times one phase of the engine: a
+        ``nanocp.<name>`` profiler span (``args`` become its arguments)
+        and ``timings["<name>_us"]``.  No flag turns it on or off: with
+        the profiler stopped the span costs about a microsecond."""
+        return _Span(self, name, args)
 
     # ------------------------------------------------------------------ #
     @staticmethod
@@ -426,14 +461,16 @@ class NanoCPEngine:
             # equal chain keys imply an equal transcript), never the
             # correctness of the first sampled token
             hit = req.prefix_hit_tokens
-            toks = jnp.asarray(self._prompts[req.rid])[None, :]
-            logits, caches = transformer.forward(self.cfg, self.params, toks,
-                                                 collect_kv=True)
-            # the FIRST generated token is sampled from the prefill logits;
-            # the decode loop then extends from it.  Keep the argmax on
-            # device — ONE batched readback happens after every forward has
-            # been enqueued (admission-path readback)
-            firsts.append(jnp.argmax(logits[0, -1]))
+            with self.span("prefill.forward", rid=req.rid,
+                           tokens=req.prompt_len):
+                toks = jnp.asarray(self._prompts[req.rid])[None, :]
+                logits, caches = transformer.forward(
+                    self.cfg, self.params, toks, collect_kv=True)
+                # the FIRST generated token is sampled from the prefill
+                # logits; the decode loop then extends from it.  Keep the
+                # argmax on device — ONE batched readback happens after
+                # every forward has been enqueued (admission-path readback)
+                firsts.append(jnp.argmax(logits[0, -1]))
             ks, vs, lats, convs, hs = [], [], [], [], []
             for li, kind in enumerate(pattern):
                 aux = caches[li]
@@ -467,17 +504,19 @@ class NanoCPEngine:
                 ssm_h.append(jnp.stack(hs, axis=1)[:, :, None])
                 ssm_coords.append([inst, slot])
         eos_done = self._record_first_tokens(reqs, firsts, now)
-        if kv_k:
-            k = jnp.concatenate(kv_k, axis=2)
-            v = jnp.concatenate(kv_v, axis=2) if kv_v else None
-            coords = np.concatenate(kv_coords, axis=1)
-            self.state = self._scatter.scatter_kv(self.state, k, v, coords)
-        if ssm_conv:
-            conv = jnp.concatenate(ssm_conv, axis=2)
-            h = jnp.concatenate(ssm_h, axis=2)
-            coords = np.asarray(ssm_coords, np.int32).T
-            self.state = self._scatter.scatter_ssm(self.state, conv, h,
-                                                   coords)
+        with self.span("prefill.scatter"):
+            if kv_k:
+                k = jnp.concatenate(kv_k, axis=2)
+                v = jnp.concatenate(kv_v, axis=2) if kv_v else None
+                coords = np.concatenate(kv_coords, axis=1)
+                self.state = self._scatter.scatter_kv(self.state, k, v,
+                                                      coords)
+            if ssm_conv:
+                conv = jnp.concatenate(ssm_conv, axis=2)
+                h = jnp.concatenate(ssm_h, axis=2)
+                coords = np.asarray(ssm_coords, np.int32).T
+                self.state = self._scatter.scatter_ssm(self.state, conv, h,
+                                                       coords)
         self._register_prefixes(reqs)
         return self._finish_prefill_eos(eos_done, now)
 
@@ -535,10 +574,12 @@ class NanoCPEngine:
         sk, sv, s_coords = [], [], []
         for req in reqs:
             enc = enc_of[req.rid]
-            toks = jnp.asarray(self._dec_prefix[req.rid])[None, :]
-            logits, caches = encdec.decode_forward(cfg, self.params, toks,
-                                                   enc, collect_kv=True)
-            firsts.append(jnp.argmax(logits[0, -1]))
+            with self.span("prefill.forward", rid=req.rid,
+                           tokens=req.prompt_len):
+                toks = jnp.asarray(self._dec_prefix[req.rid])[None, :]
+                logits, caches = encdec.decode_forward(
+                    cfg, self.params, toks, enc, collect_kv=True)
+                firsts.append(jnp.argmax(logits[0, -1]))
             kc, vc = caches["cross_kv"]          # [L, 1, S_enc, Hkv, hd]
             L_, S_enc = kc.shape[0], kc.shape[2]
             ck.append(kc[:, 0].reshape(L_, S_enc, khs, -1))
@@ -558,21 +599,24 @@ class NanoCPEngine:
                                       np.arange(T0)]).astype(np.int32))
         eos_done = self._record_first_tokens(reqs, firsts, now)
         if ck:
-            self.state = self._scatter.scatter_cross_kv(
-                self.state, jnp.concatenate(ck, axis=1),
-                jnp.concatenate(cv, axis=1),
-                np.concatenate(c_coords, axis=1))
-            self.state = self._scatter.scatter_self_kv(
-                self.state, jnp.concatenate(sk, axis=1),
-                jnp.concatenate(sv, axis=1),
-                np.concatenate(s_coords, axis=1))
+            with self.span("prefill.scatter"):
+                self.state = self._scatter.scatter_cross_kv(
+                    self.state, jnp.concatenate(ck, axis=1),
+                    jnp.concatenate(cv, axis=1),
+                    np.concatenate(c_coords, axis=1))
+                self.state = self._scatter.scatter_self_kv(
+                    self.state, jnp.concatenate(sk, axis=1),
+                    jnp.concatenate(sv, axis=1),
+                    np.concatenate(s_coords, axis=1))
         return self._finish_prefill_eos(eos_done, now)
 
     def _record_first_tokens(self, reqs: list, firsts: list, now: float):
         """One batched readback of the prefill-sampled first tokens; returns
         the requests whose first token is already EOS."""
         eos_done = []
-        for req, first in zip(reqs, jax.device_get(firsts)):
+        with self.span("prefill.readback"):
+            firsts = jax.device_get(firsts)
+        for req, first in zip(reqs, firsts):
             first = int(first)
             self.next_tok[req.rid] = first
             self.results[req.rid].tokens.append(first)
@@ -800,10 +844,10 @@ class NanoCPEngine:
         # would desynchronize tables from pools — fail loudly instead
         assert self._append_tokens, \
             "scheduler escalated on an arch whose KV the engine cannot re-shard"
-        t0 = time.perf_counter()
-        src = np.concatenate([e.src_coords for e in escalations], axis=1)
-        dst = np.concatenate([e.dst_coords for e in escalations], axis=1)
-        self.state = self._reshard(self.state, src, dst)
+        with self.span("reshard"):
+            src = np.concatenate([e.src_coords for e in escalations], axis=1)
+            dst = np.concatenate([e.dst_coords for e in escalations], axis=1)
+            self.state = self._reshard(self.state, src, dst)
         relaxed = [e for e in escalations
                    if getattr(e, "is_relaxation", False)]
         self.hot_path_stats["escalations"] += len(escalations) - len(relaxed)
@@ -811,9 +855,6 @@ class NanoCPEngine:
         self.hot_path_stats["relax_tokens"] += sum(e.tokens_moved
                                                    for e in relaxed)
         self.hot_path_stats["reshard_tokens"] += int(src.shape[1])
-        self.timings["reshard_us"] = (
-            self.timings.get("reshard_us", 0.0)
-            + (time.perf_counter() - t0) * 1e6)
 
     def _apply_copies(self, copies: list) -> None:
         """Apply owed data-plane KV copies ((src, dst) [3, T] coordinate
@@ -826,8 +867,8 @@ class NanoCPEngine:
         dst = np.concatenate([d for _, d in copies], axis=1)
         if src.shape[1] == 0:
             return
-        self.state = self._reshard(self.state, src, dst)
-        self.hot_path_stats["copy_tokens"] += int(src.shape[1])
+        with self.span("reshard"):
+            self.state = self._reshard(self.state, src, dst)
 
     def _cow_appends(self) -> None:
         """Pre-lowering CoW pass: any active request whose next decode
@@ -1294,40 +1335,40 @@ class NanoCPEngine:
         if infl is None:
             return []
         self._inflight = None
-        t0 = time.perf_counter()
-        toks = np.asarray(jax.device_get(infl.toks))
-        logits = (None if infl.logits is None
-                  else np.asarray(jax.device_get(infl.logits)))
-        self.timings["harvest_us"] = (time.perf_counter() - t0) * 1e6
+        with self.span("harvest"):
+            toks = np.asarray(jax.device_get(infl.toks))
+            logits = (None if infl.logits is None
+                      else np.asarray(jax.device_get(infl.logits)))
         self.hot_path_stats["async_token_fetches"] += 1
         done = []
-        for rid, req, i, b, last in infl.slots:
-            t = int(toks[i, b])
-            self.results[rid].tokens.append(t)
-            self.next_tok[rid] = t
-            if logits is not None:
-                self.step_logits.setdefault(rid, []).append(logits[i, b])
-            req.token_times.append(now)
-            if last:
-                # cluster bookkeeping already done at dispatch; stamp the
-                # actual emission time now that the token materialized
-                req.finish_time = now
-                self.finished.append(req)
-                done.append(req)
-            elif self.eos is not None and t == self.eos:
-                # EOS is only visible post-readback: under the lookahead
-                # pipeline the request is already lowered into the next
-                # iteration (one speculative slot whose input is patched to
-                # the stop token so the device-side mask suppresses its KV
-                # append; output discarded at the next harvest).  A request
-                # no longer active here was OOM-finished between dispatch
-                # and harvest — already reported, don't double-finish.
-                if rid in self.cluster.active:
-                    self.cluster.finish(req, now)
-                    if self.pipeline:
-                        self.hot_path_stats["speculative_slots"] += 1
+        with self.span("harvest.record"):
+            for rid, req, i, b, last in infl.slots:
+                t = int(toks[i, b])
+                self.results[rid].tokens.append(t)
+                self.next_tok[rid] = t
+                if logits is not None:
+                    self.step_logits.setdefault(rid, []).append(logits[i, b])
+                req.token_times.append(now)
+                if last:
+                    # cluster bookkeeping already done at dispatch; stamp the
+                    # actual emission time now that the token materialized
+                    req.finish_time = now
                     self.finished.append(req)
                     done.append(req)
+                elif self.eos is not None and t == self.eos:
+                    # EOS is only visible post-readback: under the lookahead
+                    # pipeline the request is already lowered into the next
+                    # iteration (one speculative slot whose input is patched to
+                    # the stop token so the device-side mask suppresses its KV
+                    # append; output discarded at the next harvest).  A request
+                    # no longer active here was OOM-finished between dispatch
+                    # and harvest — already reported, don't double-finish.
+                    if rid in self.cluster.active:
+                        self.cluster.finish(req, now)
+                        if self.pipeline:
+                            self.hot_path_stats["speculative_slots"] += 1
+                        self.finished.append(req)
+                        done.append(req)
         return done
 
     # ------------------------------------------------------------------ #
@@ -1351,25 +1392,26 @@ class NanoCPEngine:
         Returns the requests whose completion became visible during this
         call (i.e. at the harvest of the previously dispatched iteration).
         """
-        t_step = time.perf_counter()
-        now = self._now() if now is None else now
         self.timings = {}
+        with self.span("step"):
+            return self._step(self._now() if now is None else now)
 
+    def _step(self, now: float) -> list:
         # -- disaggregated cells: advance the chunk streams FIRST, so a
         #    completed handoff activates on the decode cluster before this
         #    step's schedule/lowering sees the active set -------------------
         handoff_done = []
         if self.cluster.prefill_cells:
-            t0 = time.perf_counter()
-            handoff_done = self._process_prefill_chunks(now)
-            self.timings["handoff_us"] = (time.perf_counter() - t0) * 1e6
+            with self.span("handoff"):
+                handoff_done = self._process_prefill_chunks(now)
 
         # -- schedule + admit (prefill -> on-device KV migration) ----------
-        plan = self.scheduler.schedule(self.cluster, now)
-        # requests the scheduler parked on a prefill cell this step: open
-        # their handoff tasks (first chunk forwards run next step)
-        for req in plan.staged:
-            self._stage_handoff(req)
+        with self.span("schedule"):
+            plan = self.scheduler.schedule(self.cluster, now)
+            # requests the scheduler parked on a prefill cell this step:
+            # open their handoff tasks (first chunk forwards run next step)
+            for req in plan.staged:
+                self._stage_handoff(req)
         # mid-decode CP escalations AND relaxations decided by the
         # scheduler: dispatch the live KV re-shard FIRST so the gather reads
         # the pools before this step's admissions scatter into (possibly
@@ -1401,10 +1443,9 @@ class NanoCPEngine:
         self.hot_path_stats["preemptions"] += plan.preemptions
         prefill_done = handoff_done + dropped
         if plan.admitted:
-            t0 = time.perf_counter()
-            prefill_done = prefill_done + (
-                self._prefill_batch(plan.admitted, now) or [])
-            self.timings["prefill_us"] = (time.perf_counter() - t0) * 1e6
+            with self.span("prefill"):
+                prefill_done = prefill_done + (
+                    self._prefill_batch(plan.admitted, now) or [])
         if not self.cluster.active:
             # drain a trailing iteration
             return prefill_done + self._harvest(now)
@@ -1414,35 +1455,31 @@ class NanoCPEngine:
         #    KV spill surfaces HERE (pre-flight, page table untouched): the
         #    engine escalates the request onto shards with headroom — or
         #    OOM-finishes it when none exists — and retries the lowering. ---
-        t0 = time.perf_counter()
-        spill_done = []
-        attempts = len(self.cluster.active) + 1
-        while True:
-            try:
-                if self._append_tokens:
-                    self._cow_appends()
-                tbl = routing.lower_plan(self.cluster, plan,
-                                         buckets=self.shape_buckets,
-                                         append_tokens=self._append_tokens,
-                                         next_tokens=self.next_tok,
-                                         arena=self._arena)
-                break
-            except KVSpillError as err:
-                attempts -= 1
-                if attempts <= 0:
-                    raise
-                spill_done += self._handle_spill(err, now)
-                if not self.cluster.active:
-                    return prefill_done + spill_done + self._harvest(now)
-        key = self.aot.quantise(tbl.M, tbl.S, tbl.MB, tbl.W, tbl.R)
-        # lower_plan already quantised MB on the same (idempotent) ladder;
-        # a mismatch would mean the arena buffers no longer match the AOT
-        # executable's expected shape
-        assert key[2] == tbl.MB, (key, tbl.MB)
-        self.timings["lower_us"] = (time.perf_counter() - t0) * 1e6
-        t0 = time.perf_counter()
-        fn = self.aot.lookup_key(key)
-        self.timings["lookup_us"] = (time.perf_counter() - t0) * 1e6
+        with self.span("lower"):
+            spill_done = []
+            attempts = len(self.cluster.active) + 1
+            while True:
+                try:
+                    if self._append_tokens:
+                        self._cow_appends()
+                    tbl = routing.lower_plan(
+                        self.cluster, plan, buckets=self.shape_buckets,
+                        append_tokens=self._append_tokens,
+                        next_tokens=self.next_tok, arena=self._arena)
+                    break
+                except KVSpillError as err:
+                    attempts -= 1
+                    if attempts <= 0:
+                        raise
+                    spill_done += self._handle_spill(err, now)
+                    if not self.cluster.active:
+                        return prefill_done + spill_done + self._harvest(now)
+            key = self.aot.quantise(tbl.M, tbl.S, tbl.MB, tbl.W, tbl.R)
+            # lower_plan already quantised MB on the same (idempotent)
+            # ladder; a mismatch would mean the arena buffers no longer
+            # match the AOT executable's expected shape
+            assert key[2] == tbl.MB, (key, tbl.MB)
+            fn = self.aot.lookup_key(key)
 
         # -- harvest the previous iteration (tokens usually already home) --
         # (slot snapshot only needed when a harvested EOS can leave a
@@ -1452,70 +1489,74 @@ class NanoCPEngine:
                           if self.eos is not None and self.pipeline else None)
         done = prefill_done + spill_done + self._harvest(now)
 
-        # -- patch per-slot input tokens now that they are all known -------
-        for rid in self.cluster.active:
-            i, b = self.cluster.slot_map[rid]
-            tbl.slot_token[i, b] = self.next_tok[rid]
-        if slots_at_lower is not None:
-            # EOS finishes discovered at this harvest are already lowered
-            # into THIS iteration (the one speculative slot-step): feed the
-            # stop token as their input so the device-side check masks the
-            # KV append and the sampled output
-            for req in done:
-                loc = slots_at_lower.get(req.rid)
-                if loc is not None:
-                    tbl.slot_token[loc[0], loc[1]] = self.eos
-        tbl_dev = routing.as_device_arrays(tbl, self._table_shardings_for(tbl))
+        # -- patch per-slot input tokens now that they are all known, and
+        #    put the tables on the device -----------------------------------
+        with self.span("upload"):
+            for rid in self.cluster.active:
+                i, b = self.cluster.slot_map[rid]
+                tbl.slot_token[i, b] = self.next_tok[rid]
+            if slots_at_lower is not None:
+                # EOS finishes discovered at this harvest are already
+                # lowered into THIS iteration (the one speculative
+                # slot-step): feed the stop token as their input so the
+                # device-side check masks the KV append and the sampled
+                # output
+                for req in done:
+                    loc = slots_at_lower.get(req.rid)
+                    if loc is not None:
+                        tbl.slot_token[loc[0], loc[1]] = self.eos
+            tbl_dev = routing.as_device_arrays(
+                tbl, self._table_shardings_for(tbl))
 
         # -- dispatch (async) + start the token readback copy --------------
-        t0 = time.perf_counter()
-        check = self.aot.should_audit_donation()
-        in_ptrs = self.aot.buffer_ptrs(self.state) if check else None
-        self.state, toks, step_logits = fn(self.decode_params, self.state,
-                                           tbl_dev)
-        if not self.keep_logits:
-            step_logits = None
-        try:
-            toks.copy_to_host_async()
-        except AttributeError:
-            pass
-        self.timings["dispatch_us"] = (time.perf_counter() - t0) * 1e6
-        if check:
-            self.aot.note_donation(in_ptrs, self.state)
+        with self.span("dispatch"):
+            check = self.aot.should_audit_donation()
+            in_ptrs = self.aot.buffer_ptrs(self.state) if check else None
+            self.state, toks, step_logits = fn(self.decode_params,
+                                               self.state, tbl_dev)
+            if not self.keep_logits:
+                step_logits = None
+            try:
+                toks.copy_to_host_async()
+            except AttributeError:
+                pass
 
         # -- dispatch-time bookkeeping: the iteration WILL emit one token
         #    per active slot; length-based finishes are deterministic, so
         #    free their pages/slots for the next schedule immediately ------
-        snapshot = []
-        length_done = []
-        holders = {}
-        pt = self.cluster.page_table
-        for rid in list(self.cluster.active):
-            req = self.cluster.active[rid]
-            i, b = self.cluster.slot_map[rid]
-            req.generated += 1
-            last = len(self.results[rid].tokens) + 1 >= req.max_new_tokens
-            snapshot.append((rid, req, i, b, last))
-            # the iteration's blast radius for this request: every instance
-            # holding one of its KV shards, plus the decode-slot instance —
-            # recorded BEFORE length-finishes free the pages, so a failure
-            # between dispatch and harvest can still identify affected rows
-            holders[rid] = frozenset(
-                s for s, t in pt.shard_tokens(rid).items() if t > 0) | {i}
-            if last:
-                length_done.append(req)
-        for req in length_done:
-            self.cluster.finish(req, now)
-        self._inflight = _Inflight(toks, snapshot, holders, step_logits)
-        self.iterations += 1
-        self.last_bucket = key
-        self.last_rounds_used = tbl.R
-        self.hot_path_stats["steps"] += 1
+        with self.span("bookkeep"):
+            if check:
+                self.aot.note_donation(in_ptrs, self.state)
+            snapshot = []
+            length_done = []
+            holders = {}
+            pt = self.cluster.page_table
+            for rid in list(self.cluster.active):
+                req = self.cluster.active[rid]
+                i, b = self.cluster.slot_map[rid]
+                req.generated += 1
+                last = len(self.results[rid].tokens) + 1 >= req.max_new_tokens
+                snapshot.append((rid, req, i, b, last))
+                # the iteration's blast radius for this request: every
+                # instance holding one of its KV shards, plus the
+                # decode-slot instance — recorded BEFORE length-finishes
+                # free the pages, so a failure between dispatch and harvest
+                # can still identify affected rows
+                holders[rid] = frozenset(
+                    s for s, t in pt.shard_tokens(rid).items() if t > 0) | {i}
+                if last:
+                    length_done.append(req)
+            for req in length_done:
+                self.cluster.finish(req, now)
+            self._inflight = _Inflight(toks, snapshot, holders, step_logits)
+            self.iterations += 1
+            self.last_bucket = key
+            self.last_rounds_used = tbl.R
+            self.hot_path_stats["steps"] += 1
         if not self.pipeline:
             # non-pipelined reference semantics: harvest this very iteration
             # so EOS finishes are visible before the next lowering
             done += self._harvest(now)
-        self.timings["step_us"] = (time.perf_counter() - t_step) * 1e6
         return done
 
     def run(self, max_iters: int = 1000) -> dict:
