@@ -488,16 +488,15 @@ def _dcp_attention(cfg, dims: DecodeDims, q, k_pool, v_pool, new_k, new_v,
     else:
         bt_dev, len_dev = tbl["work_bt"][0], tbl["work_len"][0]
     kp = k_pool.reshape(Fp, page, kg, dk)                          # [F',page,kg,dk]
-    vp = (v_pool.reshape(Fp, page, kg, dv) if v_pool is not None
-          else kp[..., :dv])
+    # MLA passes no V pool: the kernel reads V from the latent it holds
+    vp = v_pool.reshape(Fp, page, kg, dv) if v_pool is not None else None
     out, lse = ops.paged_decode_attention(
         q_work, kp, vp, bt_dev, len_dev,
         scale=dk ** -0.5 if cfg.attention != "mla" else
         (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5,
         # fused dequant: per-page scales follow the same local frame ids as
-        # the sub-pool; MLA's shared latent pool reuses k_scale for v.
-        k_scale=k_scale,
-        v_scale=(v_scale if v_pool is not None else k_scale))
+        # the sub-pool; MLA's shared latent pool has k_scale alone.
+        k_scale=k_scale, v_scale=v_scale, v_dim=dv)
     if ps > 1:
         # merge the stripe partials within the subgroup, slice back to hl
         g_o = jax.lax.all_gather(out, dims.model, axis=0,

@@ -96,26 +96,30 @@ def attention(q, k, v, **kw):
 # --------------------------------------------------------------------------- #
 def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
                            scale: float | None = None,
-                           k_scale=None, v_scale=None):
+                           k_scale=None, v_scale=None,
+                           v_dim: int | None = None):
     """Paged decode attention with LSE. See ``ref.paged_decode_attention``.
 
     q [N, Hq, Dk]; pages [P, page, Hkv, D] (per-device sub-pool view: the
     stripe (ps) dim is resolved by the caller's frame indices, the group
-    (kg) dim is the Hkv axis).  Quantized (fp8/int8) pools additionally
-    pass per-page ``k_scale``/``v_scale`` [P] f32 — dequant is fused into
-    whichever impl runs (``kernels/quant.py`` defines the format).  Pinned
-    by tests/test_kernels.py::test_paged_decode_vs_oracle and
+    (kg) dim is the Hkv axis).  ``v_pages=None`` means V is the first
+    ``v_dim`` lanes of each K head (MLA's shared latent pool), read once
+    per page.  Quantized (fp8/int8) pools additionally pass per-page
+    ``k_scale`` and, with a V pool, ``v_scale`` [P] f32 — dequant is fused
+    into whichever impl runs (``kernels/quant.py`` defines the format).
+    Pinned by tests/test_kernels.py::test_paged_decode_vs_oracle and
     tests/test_quant.py.
     """
     impl = _backend()
     if impl == "ref":
         return ref.paged_decode_attention(q, k_pages, v_pages, block_tables,
                                           lengths, scale=scale,
-                                          k_scale=k_scale, v_scale=v_scale)
+                                          k_scale=k_scale, v_scale=v_scale,
+                                          v_dim=v_dim)
     from . import paged_attention as pa
     return pa.paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
                                      scale=scale, k_scale=k_scale,
-                                     v_scale=v_scale,
+                                     v_scale=v_scale, v_dim=v_dim,
                                      interpret=(impl == "pallas_interpret"))
 
 

@@ -129,19 +129,22 @@ def flash_attention_blockwise(q, k, v, *, causal: bool = True,
 # --------------------------------------------------------------------------- #
 def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
                            scale: float | None = None,
-                           k_scale=None, v_scale=None):
+                           k_scale=None, v_scale=None,
+                           v_dim: int | None = None):
     """Decode attention over a paged KV pool, with LSE output.
 
     q:            [N, Hq, Dk]      one query token per work row
     k_pages:      [P, page, Hkv, Dk]
-    v_pages:      [P, page, Hkv, Dv]
+    v_pages:      [P, page, Hkv, Dv], or None: V is then the first ``v_dim``
+                  lanes of each K head (MLA's shared latent pool)
     block_tables: [N, MB] int32    page ids per row (entries >= lengths ignored)
     lengths:      [N]     int32    valid kv tokens per row; 0 => inactive row
     k_scale/v_scale: optional [P] f32 per-page dequant scales for quantized
                   (fp8/int8) pools; when given, gathered pages decode as
                   ``page * scale`` before use (``kernels/quant.py``). Pass
-                  neither (bf16) or both; for MLA's shared pool pass the
-                  same array twice.
+                  neither (bf16) or both; a shared V (``v_pages=None``)
+                  takes ``k_scale`` and no ``v_scale``.
+    v_dim:        Dv when ``v_pages`` is None.
     Returns out [N, Hq, Dv] (q.dtype), lse [N, Hq] (f32; -inf-ish for len 0).
 
     Layout contract: pages are the per-device sub-pool view [F', page, kg, D]
@@ -154,7 +157,8 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
     orig_dtype = q.dtype
     N, Hq, Dk = q.shape
     P, page, Hkv, _ = k_pages.shape
-    Dv = v_pages.shape[-1]
+    shared_v = v_pages is None
+    Dv = v_dim if shared_v else v_pages.shape[-1]
     MB = block_tables.shape[1]
     G = Hq // Hkv
     scale = scale if scale is not None else Dk ** -0.5
@@ -163,19 +167,26 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
     # accumulation avoid ever materialising head-expanded / f32 KV copies
     # (this path is what the CPU dry-run lowers — memory must stay honest).
     k = k_pages[block_tables].reshape(N, MB * page, Hkv, Dk)
-    v = v_pages[block_tables].reshape(N, MB * page, Hkv, Dv)
     if k_scale is not None:
         # quantized pools: dequant only the gathered [N, MB*page] window.
         # Scales are per page, constant across the page's tokens/head-dims.
         ks = jnp.broadcast_to(k_scale[block_tables][..., None],
                               block_tables.shape + (page,)).reshape(N, MB * page)
-        vs = jnp.broadcast_to(v_scale[block_tables][..., None],
-                              block_tables.shape + (page,)).reshape(N, MB * page)
         k = k.astype(jnp.float32) * ks[..., None, None]
-        v = v.astype(jnp.float32) * vs[..., None, None]
-    qg = (q.astype(jnp.float32) * scale).reshape(N, Hkv, G, Dk).astype(q.dtype)
+    if shared_v:
+        v = k[..., :Dv]              # the latent, gathered (and dequantized) once
+    else:
+        v = v_pages[block_tables].reshape(N, MB * page, Hkv, Dv)
+        if v_scale is not None:
+            vs = jnp.broadcast_to(v_scale[block_tables][..., None],
+                                  block_tables.shape + (page,)
+                                  ).reshape(N, MB * page)
+            v = v.astype(jnp.float32) * vs[..., None, None]
+    # scale the f32 scores, not q: q * scale rounded back to a bf16 q is
+    # off by up to half a bf16 step of every score
+    qg = q.reshape(N, Hkv, G, Dk)
     s = jnp.einsum("nhgd,nkhd->nhgk", qg, k,
-                   preferred_element_type=jnp.float32)  # [N, Hkv, G, L]
+                   preferred_element_type=jnp.float32) * scale  # [N,Hkv,G,L]
     valid = jnp.arange(MB * page)[None, :] < lengths[:, None]
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)

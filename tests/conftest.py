@@ -26,6 +26,19 @@ def run_integration(script: str, *args: str, devices: int = 8,
     return proc.stdout
 
 
+def junk_past_length(bt, lengths, page, nan_frame):
+    """The block table with every entry at or past its row's length
+    replaced by the id of a NaN-filled frame or an id out of range: a
+    paged kernel that reads a page past a length returns NaN there."""
+    import numpy as np
+    import jax.numpy as jnp
+    MB = bt.shape[1]
+    past = np.arange(MB)[None, :] * page >= np.asarray(lengths)[:, None]
+    junk = np.choose(np.arange(MB) % 3, [nan_frame, nan_frame + 5, -3])
+    return jnp.asarray(np.where(past, junk[None, :], np.asarray(bt)),
+                       jnp.int32)
+
+
 @pytest.fixture(scope="session")
 def rng():
     import numpy as np

@@ -1,39 +1,109 @@
 """Pallas kernel sweeps vs the jnp oracles (interpret mode on CPU)."""
+import zlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import junk_past_length
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.paged_attention import paged_decode_attention
+from repro.kernels.paged_attention import (BLOCK_BYTES, pages_per_block,
+                                           paged_decode_attention)
 
 
-@pytest.mark.parametrize("N,Hq,Hkv,Dk,Dv,page,MB,dtype", [
-    (4, 8, 2, 128, 128, 16, 4, jnp.float32),     # GQA
-    (3, 4, 1, 256, 128, 8, 3, jnp.bfloat16),     # MLA-like (Dk != Dv, MQA)
-    (5, 8, 8, 64, 64, 32, 2, jnp.float32),       # MHA
-    (2, 16, 4, 128, 128, 64, 2, jnp.bfloat16),   # wide GQA, big pages
-    (1, 2, 1, 128, 128, 8, 1, jnp.float32),      # single row/page
-])
-def test_paged_decode_vs_oracle(rng, N, Hq, Hkv, Dk, Dv, page, MB, dtype):
+@pytest.mark.parametrize("N,Hq,Hkv,Dk,Dv,page,MB,dtype,shared_v", [
+    (4, 8, 2, 128, 128, 16, 4, jnp.float32, False),     # GQA
+    (3, 4, 1, 256, 128, 8, 3, jnp.bfloat16, False),     # MLA-like (Dk != Dv, MQA)
+    (5, 8, 8, 64, 64, 32, 2, jnp.float32, False),       # MHA
+    (2, 16, 4, 128, 128, 64, 2, jnp.bfloat16, False),   # wide GQA, big pages
+    (1, 2, 1, 128, 128, 8, 1, jnp.float32, False),      # single row/page
+    # several compute blocks a row, MB not a multiple of pages_per_block
+    (4, 16, 8, 128, 128, 16, 72, jnp.float32, False),   # 3 blocks of 32 pages
+    (4, 16, 8, 128, 128, 16, 40, jnp.bfloat16, False),  # 2 blocks of 32 pages
+    (5, 8, 2, 128, 128, 16, 300, jnp.float32, False),   # 3 blocks of 128 pages
+    # MLA's shared latent (v_pages=None) against the oracle's explicit slice
+    (3, 40, 1, 288, 256, 16, 136, jnp.float32, True),   # 3 blocks of 64 pages
+    (3, 8, 1, 288, 256, 16, 8, jnp.bfloat16, True),     # one block
+], ids=["4-8-2-128-128-16-4-float32", "3-4-1-256-128-8-3-bfloat16",
+        "5-8-8-64-64-32-2-float32", "2-16-4-128-128-64-2-bfloat16",
+        "1-2-1-128-128-8-1-float32", "multiblock-gqa-f32",
+        "multiblock-gqa-bf16", "multiblock-gqa-long", "mla-shared-f32",
+        "mla-shared-bf16"])
+def test_paged_decode_vs_oracle(request, N, Hq, Hkv, Dk, Dv, page, MB, dtype,
+                                shared_v):
+    # data of its own per case, whatever ran before it in the session
+    rng = np.random.default_rng(zlib.crc32(request.node.callspec.id.encode()))
     P = 64
     q = jnp.asarray(rng.standard_normal((N, Hq, Dk)), dtype)
     kp = jnp.asarray(rng.standard_normal((P, page, Hkv, Dk)), dtype)
+    kp = kp.at[P - 1].set(jnp.nan)            # read only through junk ids
     vp = jnp.asarray(rng.standard_normal((P, page, Hkv, Dv)), dtype)
-    bt = jnp.asarray(rng.integers(0, P, (N, MB)), jnp.int32)
-    lengths = jnp.asarray(rng.integers(0, MB * page + 1, (N,)), jnp.int32)
-    lengths = lengths.at[0].set(0)               # inactive (CP padding) row
+    vp = vp.at[P - 1].set(jnp.nan)
+    bt = jnp.asarray(rng.integers(0, P - 1, (N, MB)), jnp.int32)
+    lengths = np.asarray(rng.integers(0, MB * page + 1, (N,)), np.int32)
+    lengths[0] = 0                               # inactive (CP padding) row
     if N > 1:
-        lengths = lengths.at[1].set(MB * page)   # full row
+        lengths[1] = MB * page                   # full row
+    if N > 2:
+        # ends mid-page inside the second compute block
+        ppb = pages_per_block(page, Hkv * Dk, MB)
+        lengths[2] = min(ppb * page + page // 2 + 1, MB * page)
+    if N > 4:
+        # a zero-length row between live ones: the next block's copies
+        # skip it
+        lengths[3] = 0
+        lengths[4] = max(lengths[4], 1)
+    if shared_v:
+        vp = kp[..., :Dv]                        # the oracle's explicit slice
     o_r, l_r = ref.paged_decode_attention(q, kp, vp, bt, lengths)
-    o_k, l_k = paged_decode_attention(q, kp, vp, bt, lengths, interpret=True)
+    o_k, l_k = paged_decode_attention(
+        q, kp, None if shared_v else vp,
+        junk_past_length(bt, lengths, page, P - 1), lengths, v_dim=Dv,
+        interpret=True)
     tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
     np.testing.assert_allclose(np.asarray(o_k, np.float32),
                                np.asarray(o_r, np.float32), atol=tol)
-    active = np.asarray(lengths) > 0
+    active = lengths > 0
     np.testing.assert_allclose(np.asarray(l_k)[active], np.asarray(l_r)[active],
                                atol=1e-3)
+    assert np.all(np.asarray(l_k)[~active] <= ref.NEG_INF)
+    if shared_v:
+        # the oracle's own shared form: V sliced from the gathered latent
+        o_s, l_s = ref.paged_decode_attention(q, kp, None, bt, lengths,
+                                              v_dim=Dv)
+        np.testing.assert_array_equal(np.asarray(o_s), np.asarray(o_r))
+        np.testing.assert_array_equal(np.asarray(l_s), np.asarray(l_r))
+
+
+@pytest.mark.parametrize("page,lanes,dtype,MB", [
+    (16, 8 * 128, jnp.float32, 1024),     # phi35moe.longdecode.1c: f32 GQA
+    (16, 288, jnp.float32, 1024),         # minicpm3.longdecode.1c: f32 latent
+    (16, 8 * 128, jnp.bfloat16, 4),       # the compile tests' shapes
+    (16, 8 * 128, jnp.float32, 4),
+    (16, 288, jnp.bfloat16, 4),
+    (16, 8 * 128, jnp.int8, 4),
+    (16, 8 * 128, jnp.float8_e4m3fn, 4),
+    (16, 8 * 128, jnp.int8, 1024),
+    (16, 288, jnp.int8, 1024),
+    (16, 288, jnp.bfloat16, 1024),
+    (64, 4 * 128, jnp.bfloat16, 2),
+])
+def test_pages_per_block_fits_vmem(page, lanes, dtype, MB):
+    """A compute block never spans more pages than the table has, and its
+    f32 copy plus two K and two V storage buffers of it fit v5e's 16 MiB of
+    scoped VMEM with room for the rest of the kernel."""
+    ppb = pages_per_block(page, lanes, MB)
+    assert 1 <= ppb <= MB
+    f32_block = ppb * (-(-page // 8) * 8) * (-(-lanes // 128) * 128) * 4
+    assert f32_block <= BLOCK_BYTES
+    itemsize = jnp.dtype(dtype).itemsize
+    sub = 32 // itemsize                   # sublanes of one VMEM tile
+    buffer = ppb * (-(-page // sub) * sub) * (-(-lanes // 128) * 128) * itemsize
+    assert 4 * buffer + f32_block <= 12 << 20
+    if ppb < MB:            # the largest power of two within the budget
+        assert ppb & (ppb - 1) == 0 and 2 * f32_block > BLOCK_BYTES
 
 
 @pytest.mark.parametrize("kg,g_out", [(2, 2), (4, 1), (2, 1)])

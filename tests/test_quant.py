@@ -22,6 +22,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
+from conftest import junk_past_length
 from repro.configs import CONFIGS, reduced
 from repro.core import dcp, migrate
 from repro.core.page_table import SCALE_PENDING, GlobalPageTable
@@ -106,23 +107,40 @@ def test_quantized_paged_decode_error_bound(name, Hq, Hkv, dk, dv,
     assert float(np.max(np.abs(np.asarray(lse_q - lse)))) <= tol
 
 
-def test_pallas_interpret_matches_ref_quantized():
+@pytest.mark.parametrize("N,P,page,MB,Hq,Hkv,dk,dv", [
+    (4, 8, 16, 3, 4, 2, 32, 32),         # one compute block a row
+    (3, 80, 16, 72, 16, 8, 128, 128),    # three blocks of 32 pages, MB % 32 != 0
+    (3, 80, 16, 136, 40, 1, 288, 256),   # MLA shared latent (v_pages=None)
+], ids=["oneblock", "multiblock", "mla-shared"])
+def test_pallas_interpret_matches_ref_quantized(N, P, page, MB, Hq, Hkv, dk,
+                                                dv):
     """The FUSED per-page dequant inside the Pallas kernel computes the
     same function as the reference's gather-then-dequant (same quantized
-    operands, same scales) — interpret mode, so it runs anywhere."""
+    operands, same scales) — interpret mode, so it runs anywhere.  Each
+    page's scale is its own amax's, so a page of a multi-page block read
+    with a neighbour's scale would show.  The kernel's table holds junk
+    ids past each row's length (a NaN frame, ids out of range), whose
+    pages and scales must weigh nothing."""
     rng = np.random.default_rng(2)
-    N, P, page, MB, Hq, Hkv, d = 4, 8, 16, 3, 4, 2, 32
-    q = jnp.asarray(rng.standard_normal((N, Hq, d)), jnp.float32)
-    _, kq, ks = _quantized_pages(rng, P, page, Hkv, d, "fp8")
-    _, vq, vs = _quantized_pages(rng, P, page, Hkv, d, "fp8")
-    bt = jnp.asarray(rng.integers(0, P, (N, MB)), jnp.int32)
+    shared = dk != dv
+    q = jnp.asarray(rng.standard_normal((N, Hq, dk)), jnp.float32)
+    _, kq, ks = _quantized_pages(rng, P, page, Hkv, dk, "fp8")
+    kq, ks = kq.at[P - 1].set(jnp.nan), ks.at[P - 1].set(jnp.nan)
+    if shared:
+        vq, vs = None, None
+    else:
+        _, vq, vs = _quantized_pages(rng, P, page, Hkv, dv, "fp8")
+        vq, vs = vq.at[P - 1].set(jnp.nan), vs.at[P - 1].set(jnp.nan)
+    bt = jnp.asarray(rng.integers(0, P - 1, (N, MB)), jnp.int32)
     lengths = jnp.asarray(rng.integers(1, MB * page + 1, (N,)), jnp.int32)
+    lengths = lengths.at[0].set(MB * page - page // 2)
 
     o_ref, l_ref = ref.paged_decode_attention(q, kq, vq, bt, lengths,
-                                              k_scale=ks, v_scale=vs)
-    o_pl, l_pl = pa.paged_decode_attention(q, kq, vq, bt, lengths,
-                                           k_scale=ks, v_scale=vs,
-                                           interpret=True)
+                                              k_scale=ks, v_scale=vs,
+                                              v_dim=dv)
+    o_pl, l_pl = pa.paged_decode_attention(
+        q, kq, vq, junk_past_length(bt, lengths, page, P - 1), lengths,
+        k_scale=ks, v_scale=vs, v_dim=dv, interpret=True)
     np.testing.assert_allclose(np.asarray(o_pl), np.asarray(o_ref),
                                atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(np.asarray(l_pl), np.asarray(l_ref),

@@ -10,6 +10,7 @@ TPU compiler takes a process-wide lock, so it must happen in the one test
 worker that runs this file, never while modules are imported.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -47,29 +48,111 @@ def _compiled_hlo(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("Hq,Hkv,Dk,Dv,kv_dtype", [
-    (32, 8, 128, 128, jnp.bfloat16),      # Phi-3.5-MoE at tp=1 (GQA)
-    (32, 8, 128, 128, jnp.float32),       # ... with the engine's f32 pools
-    (40, 1, 288, 256, jnp.bfloat16),      # MiniCPM3 MLA latent (Dk != Dv)
-    (32, 8, 128, 128, jnp.int8),          # quantized pools, per-page scales
-    (32, 8, 128, 128, jnp.float8_e4m3fn),
-], ids=["phi3.5-gqa-bf16", "phi3.5-gqa-f32", "minicpm3-mla-bf16",
-        "gqa-int8", "gqa-fp8"])
-def test_paged_decode_compiles_for_v5e(one_chip, Hq, Hkv, Dk, Dv, kv_dtype):
-    args = [_sds(one_chip, (N, Hq, Dk), jnp.bfloat16),
-            _sds(one_chip, (P, PAGE, Hkv, Dk), kv_dtype),
-            _sds(one_chip, (P, PAGE, Hkv, Dv), kv_dtype),
-            _sds(one_chip, (N, MB), jnp.int32),
-            _sds(one_chip, (N,), jnp.int32)]
-    if kv_dtype in (jnp.bfloat16, jnp.float32):
-        fn = pa.paged_decode_attention
-    else:
-        args += [_sds(one_chip, (P,), jnp.float32)] * 2
+def _kernel_operand_layouts(hlo: str, lead: int) -> list[str]:
+    """Layouts of the Pallas custom call's operands whose leading dim is
+    ``lead`` (the pools), as the compiled program lays them out."""
+    call = next(l for l in hlo.splitlines() if "tpu_custom_call" in l)
+    names = re.findall(r"%([\w.\-]+)",
+                       call.split("custom-call(", 1)[1].split(")", 1)[0])
+    layouts = []
+    for name in names:
+        m = re.search(rf"%{re.escape(name)} = \w+\[(\d+)[,\]][^{{]*\{{([^}}]*)\}}",
+                      hlo)
+        if m and int(m.group(1)) == lead:
+            layouts.append(m.group(2))
+    return layouts
 
-        def fn(q, k, v, bt, ln, ks, vs):
-            return pa.paged_decode_attention(q, k, v, bt, ln, k_scale=ks,
-                                             v_scale=vs)
-    assert "tpu_custom_call" in _compiled_hlo(fn, *args)
+
+@pytest.mark.parametrize("Hq,Hkv,Dk,Dv,kv_dtype,shared_v,n,mb,p", [
+    (32, 8, 128, 128, jnp.bfloat16, False, N, MB, P),   # Phi-3.5-MoE at tp=1 (GQA)
+    (32, 8, 128, 128, jnp.float32, False, N, MB, P),    # ... with the engine's f32 pools
+    (40, 1, 288, 256, jnp.bfloat16, False, N, MB, P),   # MiniCPM3 MLA latent (Dk != Dv)
+    (32, 8, 128, 128, jnp.int8, False, N, MB, P),       # quantized pools, per-page scales
+    (32, 8, 128, 128, jnp.float8_e4m3fn, False, N, MB, P),
+    # MLA's one latent pool, V read from K's lanes (v_pages=None)
+    (40, 1, 288, 256, jnp.float32, True, N, MB, P),
+    (40, 1, 288, 256, jnp.int8, True, N, MB, P),
+    # the chip cells' decode buckets: f32 pools, ~1024 page slots a row
+    (32, 8, 128, 128, jnp.float32, False, 8, 1024, 8193),   # phi35moe.longdecode.1c
+    (40, 1, 288, 256, jnp.float32, True, 4, 1024, 3841),    # minicpm3.longdecode.1c
+], ids=["phi3.5-gqa-bf16", "phi3.5-gqa-f32", "minicpm3-mla-bf16",
+        "gqa-int8", "gqa-fp8", "minicpm3-mla-f32-shared",
+        "minicpm3-mla-int8-shared", "phi3.5-cell-f32-mb1024",
+        "minicpm3-cell-f32-shared-mb1024"])
+def test_paged_decode_compiles_for_v5e(one_chip, Hq, Hkv, Dk, Dv, kv_dtype,
+                                       shared_v, n, mb, p):
+    quantized = kv_dtype not in (jnp.bfloat16, jnp.float32)
+    args = [_sds(one_chip, (n, Hq, Dk), jnp.bfloat16),
+            _sds(one_chip, (p, PAGE, Hkv, Dk), kv_dtype),
+            None if shared_v else _sds(one_chip, (p, PAGE, Hkv, Dv), kv_dtype),
+            _sds(one_chip, (n, mb), jnp.int32),
+            _sds(one_chip, (n,), jnp.int32)]
+    args += [_sds(one_chip, (p,), jnp.float32) if quantized else None,
+             _sds(one_chip, (p,), jnp.float32)
+             if quantized and not shared_v else None]
+
+    def fn(q, k, v, bt, ln, ks, vs):
+        return pa.paged_decode_attention(q, k, v, bt, ln, k_scale=ks,
+                                         v_scale=vs, v_dim=Dv)
+    hlo = _compiled_hlo(fn, *args)
+    assert "tpu_custom_call" in hlo
+    # the pools reach the kernel in 128-lane tiles, which the page copies'
+    # whole-tile reads rely on
+    layouts = _kernel_operand_layouts(hlo, p)
+    assert len(layouts) == (1 if shared_v else 2), layouts
+    for layout in layouts:
+        assert re.search(r":T\(\d+,128\)", layout), layout
+
+
+@pytest.mark.parametrize("Hq,Hkv,Dk,Dv,shared_v,n,p", [
+    (40, 1, 288, 256, True, 4, 3841),      # minicpm3.longdecode.1c
+    (32, 8, 128, 128, False, 8, 8193),     # phi35moe.longdecode.1c
+], ids=["minicpm3-cell", "phi3.5-cell"])
+def test_paged_decode_adds_no_pool_copy_in_the_step(one_chip, Hq, Hkv, Dk,
+                                                    Dv, shared_v, n, p):
+    """The decode step's shape around the kernel: each layer's f32 pool is
+    sliced out of the stacked serve state, takes the new token, is read by
+    the kernel and written back (``build_decode_step``'s pool carry).  XLA
+    puts the slice where it likes (MiniCPM3's fits VMEM); the kernel reads
+    it there, so no copy of a whole pool may appear in the program.  A
+    memory-space pin on the kernel's pool operands adds one per layer."""
+    layers, mb = 2, 1024
+
+    def step(k_st, v_st, q, bt, ln, slot):
+        def layer(carry, i):
+            x, k_st, v_st = carry
+            new = x[0, :1, :Dk].astype(jnp.float32)
+
+            def take(st):
+                pool = jax.lax.dynamic_index_in_dim(st, i, 0, keepdims=False)
+                return pool.at[slot // PAGE, slot % PAGE].set(
+                    jnp.broadcast_to(new, pool.shape[2:]))
+            kp = take(k_st)
+            vp = None if shared_v else take(v_st)
+            o, _ = pa.paged_decode_attention(x, kp, vp, bt, ln, v_dim=Dv)
+            x = x + jnp.pad(o, ((0, 0), (0, 0), (0, Dk - Dv))).astype(x.dtype)
+            k_st = jax.lax.dynamic_update_index_in_dim(k_st, kp[None], i, 0)
+            if not shared_v:
+                v_st = jax.lax.dynamic_update_index_in_dim(v_st, vp[None],
+                                                           i, 0)
+            return (x, k_st, v_st), None
+        (x, k_st, v_st), _ = jax.lax.scan(layer, (q, k_st, v_st),
+                                          jnp.arange(layers))
+        return x, k_st, v_st
+
+    pool = (layers, p, PAGE, Hkv, Dk)
+    args = [_sds(one_chip, pool, jnp.float32),
+            _sds(one_chip, pool[:-1] + (Dv,), jnp.float32),
+            _sds(one_chip, (n, Hq, Dk), jnp.bfloat16),
+            _sds(one_chip, (n, mb), jnp.int32),
+            _sds(one_chip, (n,), jnp.int32),
+            _sds(one_chip, (), jnp.int32)]
+    hlo = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    copies = [l for l in hlo.splitlines()
+              if re.search(rf"= \(?\w+\[{p},", l)
+              and re.search(r"\bcopy(-start)?\(", l)]
+    assert not copies, copies[:2]
 
 
 def test_flash_prefill_compiles_for_v5e_at_any_length(one_chip, monkeypatch):
