@@ -1,20 +1,21 @@
 """Decides ``correct``: the served tokens against the fp32 reference.
 
-After the window, a sample of the served requests, drawn from the seed
-and always holding the longest (in a closed set: every session), is run
-once through the reference over prompt + served tokens.  In each request
-a fixed share of the sample's positions, drawn from the seed and always
-holding the last, is compared, so every live slot is covered and a run
-compares as many positions whatever its window.  At those positions the
-number compared is the widest gap by which a served token's reference
-logit lies below the reference's best.  The engine decodes greedily, so
-a correct engine only ever serves a token that the reference also ranks
-first or nearly so (ties broken by rounding); a wrong page, expert,
-scale or token shows as a wide gap.
+The reference is the cell's architecture's ``logits_and_margins``
+(``archs/<name>.py``).  After the window, a sample of the served
+requests, drawn from the seed and always holding the longest (in a
+closed set: every session), is run once through it over prompt + served
+tokens.  In each request a fixed share of the sample's positions, drawn
+from the seed and always holding the last, is compared, so every live
+slot is covered and a run compares as many positions whatever its
+window.  At those positions the number compared is the widest gap by
+which a served token's reference logit lies below the reference's best.
+The engine decodes greedily, so a correct engine only ever serves a
+token that the reference also ranks first or nearly so (ties broken by
+rounding); a wrong page, expert, scale or token shows as a wide gap.
 
 At a position where the reference's own expert choice is nearly tied
-(the k-th expert's router probability within ``min_margin`` of the best
-one left out), bf16 rounding in a correct engine can pick the other
+(its router margin, as the architecture defines it, under
+``min_margin``), bf16 rounding in a correct engine can pick the other
 expert and move that position's logits by O(1); such positions are left
 out by that rule on the reference, not by name (``PERF.md`` gives the
 readings).
@@ -28,8 +29,6 @@ The limit lies between the two readings (``PERF.md``).
 from __future__ import annotations
 
 import numpy as np
-
-from . import reference
 
 
 def sample(served: list, seed: int, max_requests: int,
@@ -56,28 +55,28 @@ def sample(served: list, seed: int, max_requests: int,
     return out
 
 
-def _positions(model, params, s: dict, quant=None):
+def _positions(arch, model, params, s: dict, quant=None):
     prompt, toks = list(s["prompt"]), list(s["tokens"])
     seq = prompt + toks[:-1]
-    ref, margin = reference.logits_and_margins(
+    ref, margin = arch.logits_and_margins(
         model, params, seq, start=len(prompt) - 1, n=len(toks), quant=quant)
     return ref[s["at"]], margin[s["at"]]
 
 
-def gaps(model, params, s: dict) -> tuple[np.ndarray, np.ndarray]:
+def gaps(arch, model, params, s: dict) -> tuple[np.ndarray, np.ndarray]:
     """At each compared position: (reference best logit minus the served
     token's, the reference's router margin there)."""
-    ref, margin = _positions(model, params, s)
+    ref, margin = _positions(arch, model, params, s)
     toks = np.asarray(s["tokens"])[s["at"]]
     return ref.max(-1) - ref[np.arange(len(toks)), toks], margin
 
 
-def control_gaps(model, params, s: dict):
+def control_gaps(arch, model, params, s: dict):
     """(program-side gaps, router margins, control gaps) at the same
     positions: the control's token is the one the fp8-weight reference
     ranks first."""
-    ref, margin = _positions(model, params, s)
-    low, _ = _positions(model, params, s, quant="fp8")
+    ref, margin = _positions(arch, model, params, s)
+    low, _ = _positions(arch, model, params, s, quant="fp8")
     toks = np.asarray(s["tokens"])[s["at"]]
     idx = np.arange(len(toks))
     best = ref.max(-1)

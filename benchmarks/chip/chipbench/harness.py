@@ -31,7 +31,7 @@ import time
 import jax
 import numpy as np
 
-from . import check, stats, trace as trace_mod, traffic, weights
+from . import check, stats, trace as trace_mod, traffic
 from .spec import BENCH_DIR, ROOT, Cell, peaks, reader
 
 GIVE_UP_S = 60.0        # longest the client serves past the window close
@@ -73,29 +73,12 @@ def require_chips(n: int, allow_cpu: bool = False) -> list:
 # --------------------------------------------------------------- program
 def program_config(cell: Cell):
     """The program's ``ModelConfig`` for this configuration, checked size
-    by size against the configuration file."""
+    by size against what the cell's architecture reads from the file."""
     from repro.configs import CONFIGS
     prog = cell.config["program"]
     pcfg = dataclasses.replace(CONFIGS[prog["repo_config"]],
                                **prog.get("overrides", {}))
-    m = cell.model
-    want = {"attention": m.attention, "norm": m.norm,
-            "num_layers": m.num_layers, "d_model": m.d_model,
-            "num_heads": m.num_heads, "d_ff": m.d_ff,
-            "vocab_size": m.vocab_size, "padded_vocab": m.padded_vocab,
-            "rope_theta": m.rope_theta, "num_experts": m.num_experts,
-            "num_experts_per_tok": m.num_experts_per_tok,
-            "qkv_bias": False, "qk_norm": False, "tie_embeddings": False,
-            "act": "silu", "block_period": 1}
-    if m.attention == "mla":
-        want.update(q_lora_rank=m.q_lora_rank, kv_lora_rank=m.kv_lora_rank,
-                    qk_nope_head_dim=m.qk_nope_head_dim,
-                    qk_rope_head_dim=m.qk_rope_head_dim,
-                    v_head_dim=m.v_head_dim)
-    else:
-        want.update(num_kv_heads=m.num_kv_heads, head_dim_=m.head_dim)
-    if m.num_experts:
-        want["moe_d_ff_"] = m.moe_d_ff
+    want = cell.arch.program_sizes(cell.model)
     bad = {k: (getattr(pcfg, k), v) for k, v in want.items()
            if getattr(pcfg, k) != v}
     if bad:
@@ -248,7 +231,7 @@ def _setup(cell: Cell, seed: int, seconds: float, allow_cpu: bool):
         _COMPILES = _compile_counter()
     t_setup = time.perf_counter()
     pcfg = program_config(cell)
-    params = weights.make_params(cell.model, seed, devices[0])
+    params = cell.arch.make_params(cell.model, seed, devices[0])
     jax.block_until_ready(params)
     t_w = time.perf_counter() - t_setup
     eng = build_engine(cell, pcfg, params, devices)
@@ -396,8 +379,8 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, *,
                "tokens": list(res[t.rid].tokens)} for t in measured
               if t.stamps and (cell.traffic["kind"] == "sessions"
                                or len(t.stamps) >= t.max_new_tokens)]
-    run_rec = {"model": cell.model, "peaks": peak, "setup_s": setup_s,
-               "e2e": e2e, "steps": list(drv.steps),
+    run_rec = {"arch": cell.arch, "model": cell.model, "peaks": peak,
+               "setup_s": setup_s, "e2e": e2e, "steps": list(drv.steps),
                "trace": None}
     del eng, drv
     gc.collect()
@@ -420,11 +403,11 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, *,
     all_g, all_m, all_c = [], [], []
     for s in sample:
         if control:
-            g, mg, c = check.control_gaps(cell.model, params, s)
+            g, mg, c = check.control_gaps(cell.arch, cell.model, params, s)
             ctrl_gap.append(check.widest(c, mg, min_margin))
             all_c.append(c)
         else:
-            g, mg = check.gaps(cell.model, params, s)
+            g, mg = check.gaps(cell.arch, cell.model, params, s)
         prog_gap.append(check.widest(g, mg, min_margin))
         all_g.append(g)
         all_m.append(mg)

@@ -8,7 +8,6 @@ Nothing here comes from the program.
 """
 from __future__ import annotations
 
-import json
 from dataclasses import dataclass
 
 
@@ -70,9 +69,3 @@ def from_config(cfg: dict) -> Model:
         moe_d_ff=int(cfg.get("intermediate_size", 0)
                      if cfg.get("num_local_experts") else 0),
     )
-
-
-def load(path: str) -> tuple[dict, Model]:
-    with open(path) as f:
-        cfg = json.load(f)
-    return cfg, from_config(cfg)

@@ -1,7 +1,7 @@
 """The flash prefill kernel's share of its roofline in the traced slice:
 the least time the chip needs for the causal attention of the prompts
-prefilled there (``flops.prefill_attention``) over the kernel's device
-time from the trace, in percent."""
+prefilled there (the cell's architecture's ``prefill_attention``) over
+the kernel's device time from the trace, in percent."""
 from chipbench import flops, names, trace
 
 
@@ -13,7 +13,7 @@ def read(run):
     lens = [p for s in run["steps"] if s.traced for p in s.prefilled]
     if not n or not lens or t <= 0:
         return None
-    m = run["model"]
-    ops = sum(flops.prefill_attention(m, S)[0] for S in lens)
-    byt = sum(flops.prefill_attention(m, S)[1] for S in lens)
+    arch, m = run["arch"], run["model"]
+    ops = sum(arch.prefill_attention(m, S)[0] for S in lens)
+    byt = sum(arch.prefill_attention(m, S)[1] for S in lens)
     return 100.0 * flops.roofline_s(ops, byt, run["peaks"]) / t

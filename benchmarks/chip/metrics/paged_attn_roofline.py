@@ -1,7 +1,8 @@
 """The paged decode kernel's share of its roofline in the traced slice:
 the least time the chip needs for the traced steps' attention (KV of each
-row's actual context read once at bf16, ``flops.decode_attention``) over
-the kernel's device time from the trace, in percent."""
+row's actual context read once at bf16: the cell's architecture's
+``decode_attention``) over the kernel's device time from the trace, in
+percent."""
 from chipbench import flops, names, trace
 
 
@@ -13,7 +14,7 @@ def read(run):
     ctxs = [c for s in run["steps"] if s.traced for c in s.ctx_list]
     if not n or not ctxs or t <= 0:
         return None
-    m = run["model"]
-    ops = sum(flops.decode_attention(m, c)[0] for c in ctxs)
-    byt = sum(flops.decode_attention(m, c)[1] for c in ctxs)
+    arch, m = run["arch"], run["model"]
+    ops = sum(arch.decode_attention(m, c)[0] for c in ctxs)
+    byt = sum(arch.decode_attention(m, c)[1] for c in ctxs)
     return 100.0 * flops.roofline_s(ops, byt, run["peaks"]) / t

@@ -101,7 +101,8 @@ def test_program_span_readers():
                               "prefill_us": 4000.0, "lower_us": 0.0,
                               "dispatch_us": 0.0}, prefilled=(3,))]
     peak = {"bf16_flops": 1e9, "hbm_bytes_per_s": 1e9}
-    run = {"model": GQA, "peaks": peak, "steps": steps, "trace": None}
+    run = {"arch": spec.architecture(), "model": GQA, "peaks": peak,
+           "steps": steps, "trace": None}
     host = spec.reader("engine.host_ms_per_step")(run)
     assert host == pytest.approx(((1000 - 300) + (5000 - 100 - 4000))
                                  / 2 / 1e3)
