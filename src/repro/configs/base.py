@@ -17,6 +17,47 @@ def _round_up(x: int, m: int) -> int:
 
 
 @dataclass(frozen=True)
+class YaRN:
+    """YaRN rotary scaling (DeepSeek-V3's ``rope_scaling``, type "yarn").
+
+    Frequencies below the correction range (``beta_slow`` rotations over
+    the original context) are divided by ``factor``, those above it
+    (``beta_fast``) are kept, and a linear ramp blends the dims between;
+    the attention softmax scale is multiplied by ``mscale_all_dim``'s
+    mscale squared (``softmax_mult``).  The rotary cos/sin would be scaled
+    by mscale(``mscale``) / mscale(``mscale_all_dim``); only equal values
+    (DeepSeek-V3's 1 and 1, a ratio of 1) are supported."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
+
+    def __post_init__(self):
+        assert self.mscale == self.mscale_all_dim, (
+            "YaRN with mscale != mscale_all_dim scales the rotary cos/sin, "
+            "which is not implemented")
+
+    @staticmethod
+    def get_mscale(factor: float, mscale: float) -> float:
+        return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+    def correction_range(self, dim: int, base: float) -> tuple[int, int]:
+        def dim_of(rotations: float) -> float:
+            return dim * math.log(self.original_max_position
+                                  / (rotations * 2 * math.pi)) \
+                / (2 * math.log(base))
+        low = math.floor(dim_of(self.beta_fast))
+        high = math.ceil(dim_of(self.beta_slow))
+        return max(low, 0), min(high, dim - 1)
+
+    @property
+    def softmax_mult(self) -> float:
+        return self.get_mscale(self.factor, self.mscale_all_dim) ** 2
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     # -- identity ---------------------------------------------------------
     name: str
@@ -34,6 +75,7 @@ class ModelConfig:
     qkv_bias: bool = False
     qk_norm: bool = False
     rope_theta: float = 10_000.0
+    rope_scaling: YaRN | None = None
     # -- MLA (multi-head latent attention) --------------------------------
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
@@ -47,7 +89,21 @@ class ModelConfig:
     num_shared_experts: int = 0
     moe_every: int = 1             # MoE on layers with (idx % moe_every == moe_offset)
     moe_offset: int = 0
+    first_k_dense: int = 0         # leading dense layers before the MoE stack
     capacity_factor: float = 1.25
+    # router: "softmax" (softmax, top-k) or "sigmoid" (DeepSeek-V3's
+    # noaux_tc: sigmoid scores, + a correction bias for selection only,
+    # top-k within the top ``topk_group`` of ``n_group`` groups)
+    scoring_func: str = "softmax"
+    score_correction_bias: bool = False
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # the experts this mesh holds: ``held_experts`` ids from
+    # ``held_experts_first`` (0 -> all ``num_experts``, the router's width)
+    held_experts_first: int = 0
+    held_experts: int = 0
     # -- SSM (Mamba-2 / SSD) ------------------------------------------------
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -90,6 +146,27 @@ class ModelConfig:
         return self.moe_d_ff or self.d_ff
 
     @property
+    def num_held_experts(self) -> int:
+        return self.held_experts or self.num_experts
+
+    @property
+    def holds_share(self) -> bool:
+        """The expert layer holds a share of the router's experts; the
+        rest live on chips outside this mesh."""
+        return self.is_moe and self.num_held_experts < self.num_experts
+
+    @property
+    def rope_scaling_params(self) -> dict | None:
+        return (dataclasses.asdict(self.rope_scaling)
+                if self.rope_scaling else None)
+
+    @property
+    def mla_softmax_scale(self) -> float:
+        """(nope + rope)^-1/2, times YaRN's mscale squared."""
+        s = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        return s * self.rope_scaling.softmax_mult if self.rope_scaling else s
+
+    @property
     def is_mla(self) -> bool:
         return self.attention == "mla"
 
@@ -120,7 +197,8 @@ class ModelConfig:
                 mixer = "attn" if (i % self.attn_every == self.attn_offset) else "ssm"
             else:
                 mixer = "attn"
-            if self.is_moe and (i % self.moe_every == self.moe_offset):
+            if self.is_moe and i >= self.first_k_dense and (
+                    i % self.moe_every == self.moe_offset):
                 ffn = "moe"
             else:
                 ffn = "dense"
@@ -150,8 +228,29 @@ class ModelConfig:
         return self.num_layers // self.block_period
 
     def block_pattern(self) -> list[dict]:
-        """Layer kinds within one repeating block."""
-        return self.layer_kinds()[: self.block_period]
+        """Layer kinds within one repeating block of the main stack (after
+        any leading dense layers)."""
+        k = self.first_k_dense
+        return self.layer_kinds()[k: k + self.block_period]
+
+    def segments(self) -> list[tuple[str, list[dict], int, int]]:
+        """The trunk as ``(params key, block pattern, blocks, first block)``
+        segments, each scanned over uniform blocks: the leading dense
+        layers (``dense_blocks``), then the main stack (``blocks``).  Both
+        have the same mixers per block, so the serve state's pools stay
+        one ``[num_blocks, ...]`` buffer per kind."""
+        kd = self.first_k_dense
+        assert kd % self.block_period == 0, (
+            f"{self.name}: first_k_dense={kd} not a multiple of "
+            f"block_period={self.block_period}")
+        nd = kd // self.block_period
+        segs = []
+        if nd:
+            segs.append(("dense_blocks",
+                         self.layer_kinds()[: self.block_period], nd, 0))
+        segs.append(("blocks", self.block_pattern(), self.num_blocks - nd,
+                      nd))
+        return segs
 
     # ------------------------------------------------------------------ #
     # parameter counts (for roofline MODEL_FLOPS = 6*N*D)
@@ -186,7 +285,7 @@ class ModelConfig:
 
         def moe_ffn() -> tuple[int, int]:
             per_expert = 3 * D * self.moe_d_ff_
-            total = self.num_experts * per_expert + D * self.num_experts
+            total = self.num_held_experts * per_expert + D * self.num_experts
             total += self.num_shared_experts * 3 * D * self.moe_d_ff_
             active = (self.num_experts_per_tok + self.num_shared_experts) * per_expert \
                 + D * self.num_experts
@@ -255,6 +354,11 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
     if cfg.is_moe:
         base.update(num_experts=4, num_experts_per_tok=min(cfg.num_experts_per_tok, 2),
                     moe_d_ff=64)
+        if cfg.n_group > 1:            # group-limited: 4 groups of 4, top-2
+            base.update(num_experts=16, n_group=4, topk_group=2)
+    if cfg.first_k_dense:              # one leading dense block, then two
+        base.update(first_k_dense=cfg.block_period,
+                    num_layers=cfg.block_period * 3)
     if cfg.family in ("ssm", "hybrid"):
         base.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=32)
     if cfg.is_encoder_decoder:

@@ -261,12 +261,14 @@ def to_decode_params(cfg: ModelConfig, params: dict, tp: int) -> dict:
             out["ffn"] = lp["ffn"]
         return out
 
-    pattern = cfg.block_pattern()
-    blocks = {"layers": [
-        jax.vmap(lambda lp, kd=kind: conv_layer(lp, kd))(params["blocks"]["layers"][i])
-        for i, kind in enumerate(pattern)]}
-    return {"embed": params["embed"], "blocks": blocks,
-            "final_norm": params["final_norm"], "head": params["head"]}
+    out = {"embed": params["embed"], "final_norm": params["final_norm"],
+           "head": params["head"]}
+    for key, pattern, _, _ in cfg.segments():
+        out[key] = {"layers": [
+            jax.vmap(lambda lp, kd=kind: conv_layer(lp, kd))(
+                params[key]["layers"][i])
+            for i, kind in enumerate(pattern)]}
+    return out
 
 
 # =========================================================================== #
@@ -319,6 +321,11 @@ def init_serve_state(cfg: ModelConfig, dims: DecodeDims, num_instances: int,
         state["conv_C"] = jnp.zeros((nb, n_ssm, I, dims.M, cw - 1, ns), cdt)
         state["ssm_state"] = jnp.zeros((nb, n_ssm, I, dims.M, nh,
                                         cfg.ssm_head_dim, ns), jnp.float32)
+    if cfg.holds_share:
+        # routed assignments of live tokens that reached each held expert,
+        # summed over the MoE layers and steps (``NanoCPEngine.expert_load``)
+        state["expert_load"] = jnp.zeros((I, cfg.num_held_experts),
+                                         jnp.int32)
     return state
 
 
@@ -493,7 +500,7 @@ def _dcp_attention(cfg, dims: DecodeDims, q, k_pool, v_pool, new_k, new_v,
     out, lse = ops.paged_decode_attention(
         q_work, kp, vp, bt_dev, len_dev,
         scale=dk ** -0.5 if cfg.attention != "mla" else
-        (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5,
+        cfg.mla_softmax_scale,
         # fused dequant: per-page scales follow the same local frame ids as
         # the sub-pool; MLA's shared latent pool has k_scale alone.
         k_scale=k_scale, v_scale=v_scale, v_dim=dv)
@@ -566,14 +573,14 @@ def _attn_layer(cfg, dims, lp, x, pos, pools, tbl, hl, geom):
         else:
             qn = (h @ mx["wq"]).reshape(M, hl, dn + dr)
         q_nope, q_rope = qn[..., :dn], qn[..., dn:]
-        q_rope = L.apply_rope(q_rope, pos, cfg.rope_theta)
+        q_rope = L.apply_rope(q_rope, pos, cfg.rope_theta, cfg.rope_scaling)
         # absorb W_uk: q_latent = q_nope @ wk_b[h]  -> [M, hl, kvr]
         q_lat = jnp.einsum("mhd,hkd->mhk", q_nope, mx["wk_b"])
         q = jnp.concatenate([q_lat, q_rope], axis=-1)              # [M,hl,kvr+dr]
         kv = h @ mx["wkv_a"]
         c_kv = L.rms_norm_vec(kv[..., :kvr], mx["kv_norm"])
         k_rope = L.apply_rope(kv[..., kvr:][:, None, :], pos,
-                              cfg.rope_theta)[:, 0, :]
+                              cfg.rope_theta, cfg.rope_scaling)[:, 0, :]
         new_k = jnp.concatenate([c_kv, k_rope], axis=-1)           # [M, kvr+dr]
         merged, kp, _, ksc, _ = _dcp_attention(cfg, dims, q, pools[0], None,
                                                new_k, None, tbl, dk=kvr + dr,
@@ -596,8 +603,9 @@ def _attn_layer(cfg, dims, lp, x, pos, pools, tbl, hl, geom):
     if cfg.qk_norm:
         q = L.rms_norm_vec(q, mx["q_norm"])
         k = L.rms_norm_vec(k, mx["k_norm"])
-    q = L.apply_rope(q, pos, cfg.rope_theta)
-    k = L.apply_rope(k, pos, cfg.rope_theta).reshape(M, kg * hd)
+    q = L.apply_rope(q, pos, cfg.rope_theta, cfg.rope_scaling)
+    k = L.apply_rope(k, pos, cfg.rope_theta,
+                     cfg.rope_scaling).reshape(M, kg * hd)
     merged, kp, vp, ksc, vsc = _dcp_attention(cfg, dims, q, pools[0], pools[1],
                                               k, v, tbl, dk=hd, dv=hd,
                                               geom=geom, k_scale=pools[2],
@@ -656,7 +664,6 @@ def build_decode_step(cfg: ModelConfig, dims: DecodeDims):
     All array args are the per-device shards (leading I dim of size 1 on
     state/tables).
     """
-    pattern = cfg.block_pattern()
     geom = attn_tp_geometry(cfg, dims.tp)
     hp = geom[0]
     hl = hp // dims.tp if hp else 0
@@ -673,9 +680,15 @@ def build_decode_step(cfg: ModelConfig, dims: DecodeDims):
         # KV pools / SSM states travel as scan CARRY with per-block
         # dynamic-slice/update, so XLA's loop aliasing keeps ONE in-place
         # buffer (scan xs/ys would double-buffer them; measured 3.6x pool
-        # bytes of temp on the 14B decode cell).
-        def block_fn(carry, xs):
-            x, st = carry
+        # bytes of temp on the 14B decode cell).  Each segment of the trunk
+        # (``cfg.segments``: leading dense layers, then the main stack) is
+        # one scan over its blocks, carrying the same pools.
+        state = dict(state)
+        load = state.pop("expert_load", None)
+        live = None if load is None else tbl["slot_active"][0]
+
+        def block_fn(pattern, carry, xs):
+            x, st, load = carry
             i, bp = xs["idx"], xs["params"]
             # fp8-stored weights dequantise at use (in-register on TPU; the
             # param stream is charged at fp8 width)
@@ -747,7 +760,11 @@ def build_decode_step(cfg: ModelConfig, dims: DecodeDims):
                             f = moe_decode_ffn(cfg, lp["ffn"], h,
                                                axis=dims.data,
                                                axis_size=dims.data_size,
-                                               tp_axis=dims.model)
+                                               tp_axis=dims.model,
+                                               load_mask=live)
+                            if load is not None:
+                                f, hits = f
+                                load = load + hits[None]
                         else:
                             f = dense_decode_ffn(cfg, lp["ffn"], h,
                                                  tp_axis=dims.model)
@@ -756,11 +773,15 @@ def build_decode_step(cfg: ModelConfig, dims: DecodeDims):
                 blk_new = {k: jnp.stack(v)[:, None] for k, v in upd.items()}
                 st = {k: jax.lax.dynamic_update_index_in_dim(
                     st[k], blk_new[k], i, 0) for k in st}
-            return (x, st), None
+            return (x, st, load), None
 
-        nb = cfg.num_blocks
-        xs = {"params": params["blocks"], "idx": jnp.arange(nb)}
-        (x, new_pools), _ = jax.lax.scan(block_fn, (x, state), xs)
+        carry = (x, state, load)
+        for key, pattern, n, first in cfg.segments():
+            xs = {"params": params[key], "idx": jnp.arange(first, first + n)}
+            carry, _ = jax.lax.scan(partial(block_fn, pattern), carry, xs)
+        x, new_pools, load = carry
+        if load is not None:
+            new_pools["expert_load"] = load
 
         with jax.named_scope("head"):
             x = L.apply_norm(cfg, params["final_norm"], x)
@@ -970,6 +991,7 @@ _REPLICATED_LEAVES = frozenset({
     "wq_a", "wkv_a", "router",             # lora-down / router: small, shared
     "wB", "wC", "conv_B", "conv_C", "convb_B", "convb_C",   # SSM B/C (shared)
     "pos_dec", "bo",
+    "e_score_correction_bias",             # router selection bias [E]
 })
 _COLUMN_LEAVES = frozenset({               # shard the LAST dim over model
     "wq", "wk", "wv", "wq_b", "wz", "wx", "wdt",
@@ -1038,6 +1060,8 @@ def serve_state_specs(cfg: ModelConfig, state, *, data="data", model="model",
             specs[k] = P(None, None, da, None, None, None)
         elif k == "ssm_state":
             specs[k] = P(None, None, da, None, model, None, None)
+        elif k == "expert_load":
+            specs[k] = P(da, None)
         else:
             raise KeyError(k)
     return specs

@@ -49,8 +49,8 @@ def qkv_proj(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array,
         k = layers.rms_norm_vec(k, p["k_norm"])
     use_rope = cfg.rope if rope is None else rope
     if use_rope:
-        q = layers.apply_rope(q, positions, cfg.rope_theta)
-        k = layers.apply_rope(k, positions, cfg.rope_theta)
+        q = layers.apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+        k = layers.apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
     return q, k, v
 
 

@@ -44,18 +44,31 @@ def rms_norm_vec(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array
 # --------------------------------------------------------------------------- #
 # rotary embeddings
 # --------------------------------------------------------------------------- #
-def rope_freqs(head_dim: int, theta: float) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def rope_freqs(head_dim: int, theta: float, scaling=None) -> jax.Array:
+    """Inverse frequencies [head_dim/2]; with ``scaling`` (a
+    ``configs.base.YaRN``) the low frequencies are divided by its factor
+    and the dims of its correction range blend linearly between."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                             / head_dim))
+    if scaling is None:
+        return freqs
+    low, high = scaling.correction_range(head_dim, theta)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return freqs / scaling.factor * ramp + freqs * (1.0 - ramp)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               scaling=None) -> jax.Array:
     """x: [..., S, H, D] (or [..., H, D] with scalar-ish positions broadcast).
 
     positions: integer array broadcastable to x.shape[:-2].
-    Rotates pairs (x[2i], x[2i+1]).
+    Rotates pairs (x[2i], x[2i+1]); ``scaling``: YaRN (``rope_freqs``).
     """
     D = x.shape[-1]
-    freqs = rope_freqs(D, theta)                                   # [D/2]
+    freqs = rope_freqs(D, theta, scaling)                          # [D/2]
     ang = positions.astype(jnp.float32)[..., None, None] * freqs   # [..., 1, D/2]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x1 = x[..., 0::2].astype(jnp.float32)

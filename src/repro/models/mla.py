@@ -47,7 +47,8 @@ def mla_q(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array):
         q = x @ p["wq"]
     q = q.reshape(B, S, H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = layers.apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = layers.apply_rope(q_rope, positions, cfg.rope_theta,
+                               cfg.rope_scaling)
     return q_nope, q_rope
 
 
@@ -57,7 +58,7 @@ def mla_latent(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array):
     kv = x @ p["wkv_a"]
     c_kv = layers.rms_norm_vec(kv[..., :kvr], p["kv_norm"])
     k_rope = layers.apply_rope(kv[..., kvr:][..., None, :], positions,
-                               cfg.rope_theta)[..., 0, :]
+                               cfg.rope_theta, cfg.rope_scaling)[..., 0, :]
     return c_kv, k_rope
 
 
@@ -74,8 +75,7 @@ def mla_self_attention(cfg: ModelConfig, p: dict, x: jax.Array,
     k_rope_h = jnp.broadcast_to(k_rope[:, :, None, :], (B, S, H, dr))
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     k = jnp.concatenate([k_nope, k_rope_h], axis=-1)
-    scale = (dn + dr) ** -0.5
-    o = ops.attention(q, k, v, causal=True, scale=scale)
+    o = ops.attention(q, k, v, causal=True, scale=cfg.mla_softmax_scale)
     return o.reshape(B, S, H * dv) @ p["wo"]
 
 

@@ -19,33 +19,84 @@ from . import layers
 
 
 def make_moe_params(rng, cfg: ModelConfig) -> dict:
+    """Router over all ``num_experts``; weights of the held experts only."""
     D, E, F = cfg.d_model, cfg.num_experts, cfg.moe_d_ff_
+    H = cfg.num_held_experts
     ks = jax.random.split(rng, 5)
     p = {
         "router": layers.dense_init(ks[0], (D, E), dtype=jnp.float32),
-        "wi_gate": layers.dense_init(ks[1], (E, D, F)),
-        "wi_up": layers.dense_init(ks[2], (E, D, F)),
-        "wo": layers.dense_init(ks[3], (E, F, D)),
+        "wi_gate": layers.dense_init(ks[1], (H, D, F)),
+        "wi_up": layers.dense_init(ks[2], (H, D, F)),
+        "wo": layers.dense_init(ks[3], (H, F, D)),
     }
+    if cfg.score_correction_bias:
+        p["e_score_correction_bias"] = jnp.zeros((E,), jnp.float32)
     if cfg.num_shared_experts:
         Fs = cfg.moe_d_ff_ * cfg.num_shared_experts
         p["shared"] = layers.make_mlp_params(ks[4], cfg, d_ff=Fs)
     return p
 
 
-def router_topk(cfg: ModelConfig, router_w: jax.Array, x: jax.Array):
-    """x: [T, D] -> (weights [T, k] f32, idx [T, k] int32). Softmax-then-topk."""
-    logits = x.astype(jnp.float32) @ router_w                      # [T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    w, idx = jax.lax.top_k(probs, cfg.num_experts_per_tok)
-    w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-9)  # renormalise
+def router_topk(cfg: ModelConfig, p: dict, x: jax.Array):
+    """x: [T, D] -> (weights [T, k] f32, idx [T, k] int32) over all
+    ``num_experts``.
+
+    softmax: softmax then top-k, the k weights renormalised.
+    sigmoid (DeepSeek-V3 ``noaux_tc``): sigmoid scores; the selection adds
+    the correction bias, keeps the ``topk_group`` groups whose top-2 biased
+    scores sum highest, and takes the top-k inside them; the weights are
+    the unbiased scores, renormalised (``norm_topk_prob``) and scaled by
+    ``routed_scaling_factor``.  Ties go to the lower index."""
+    k = cfg.num_experts_per_tok
+    if cfg.scoring_func == "softmax":
+        logits = x.astype(jnp.float32) @ p["router"]               # [T, E]
+        probs = jax.nn.softmax(logits, axis=-1)
+        w, idx = jax.lax.top_k(probs, k)
+        if cfg.norm_topk_prob:
+            w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-9)
+    else:
+        assert cfg.scoring_func == "sigmoid", cfg.scoring_func
+        logits = jnp.dot(x.astype(jnp.float32), p["router"],
+                         precision=jax.lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits)                            # [T, E]
+        choice = scores
+        if cfg.score_correction_bias:
+            choice = scores + p["e_score_correction_bias"]
+        if cfg.n_group > 1:
+            T, E = choice.shape
+            grouped = choice.reshape(T, cfg.n_group, E // cfg.n_group)
+            gscore = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)  # [T, G]
+            _, gidx = jax.lax.top_k(gscore, cfg.topk_group)
+            keep = jnp.any(jnp.arange(cfg.n_group)[None, :, None]
+                           == gidx[:, None, :], axis=-1)           # [T, G]
+            choice = jnp.where(keep[:, :, None], grouped,
+                               -jnp.inf).reshape(T, E)
+        _, idx = jax.lax.top_k(choice, k)
+        w = jnp.take_along_axis(scores, idx, axis=-1)
+        if cfg.norm_topk_prob:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+    if cfg.routed_scaling_factor != 1.0:
+        w = w * cfg.routed_scaling_factor
     return w, idx.astype(jnp.int32)
 
 
-def group_by_expert(topk_idx: jax.Array, num_experts: int, capacity: int):
+def held_ids(cfg: ModelConfig, idx: jax.Array) -> jax.Array:
+    """Router expert ids -> ids among the held experts; an expert held
+    elsewhere maps to ``num_held_experts`` (never dispatched here)."""
+    if not cfg.holds_share:
+        return idx
+    local = idx - cfg.held_experts_first
+    H = cfg.num_held_experts
+    return jnp.where((local >= 0) & (local < H), local, H)
+
+
+def group_by_expert(topk_idx: jax.Array, num_experts: int, capacity: int,
+                    outside: bool = False):
     """Sort-based grouping of (token, slot) assignments into expert bins.
 
-    topk_idx: [T, k] -> returns
+    topk_idx: [T, k] ids in [0, num_experts]; with ``outside`` the id
+    ``num_experts`` marks an assignment to an expert not held here, dropped
+    like one past capacity.  Returns
       src_token [E*C] int32 (T == dropped/empty sentinel),
       slot_of   [T, k] int32 (position in the [E*C] buffer; E*C == dropped).
     """
@@ -59,6 +110,8 @@ def group_by_expert(topk_idx: jax.Array, num_experts: int, capacity: int):
     first_of = jnp.searchsorted(se, jnp.arange(num_experts), side="left")
     pos_in_e = jnp.arange(T * k, dtype=jnp.int32) - first_of[se].astype(jnp.int32)
     keep = pos_in_e < capacity
+    if outside:
+        keep = keep & (se < num_experts)
     slot = jnp.where(keep, se * capacity + pos_in_e, num_experts * capacity)
     src_token = jnp.full((num_experts * capacity + 1,), T, jnp.int32)
     src_token = src_token.at[slot].set(st, mode="drop").at[-1].set(T)
@@ -68,21 +121,30 @@ def group_by_expert(topk_idx: jax.Array, num_experts: int, capacity: int):
     return src_token[:-1], slot_of.reshape(T, k)
 
 
+def capacity(cfg: ModelConfig, T: int, capacity_factor: float | None = None):
+    """Per-expert capacity ceil(T*k/E * phi), E the router's width: at
+    phi = E/k it is T, so no expert can drop a token."""
+    phi = capacity_factor or cfg.capacity_factor
+    return max(1, math.ceil(T * cfg.num_experts_per_tok / cfg.num_experts
+                            * phi))
+
+
 def moe_ffn(cfg: ModelConfig, p: dict, x: jax.Array,
             capacity_factor: float | None = None) -> jax.Array:
-    """x: [T, D] -> [T, D].  Per-call capacity = ceil(T*k/E * phi)."""
+    """x: [T, D] -> [T, D]: the held experts' part of the routed output,
+    plus the shared expert.  Per-call capacity ``capacity``."""
     T, D = x.shape
-    E, k = cfg.num_experts, cfg.num_experts_per_tok
-    phi = capacity_factor or cfg.capacity_factor
-    C = max(1, math.ceil(T * k / E * phi))
-    w, idx = router_topk(cfg, p["router"], x)
-    src_token, slot_of = group_by_expert(idx, E, C)
+    H = cfg.num_held_experts
+    C = capacity(cfg, T, capacity_factor)
+    w, idx = router_topk(cfg, p, x)
+    src_token, slot_of = group_by_expert(held_ids(cfg, idx), H, C,
+                                         cfg.holds_share)
 
     x_pad = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)], axis=0)
-    expert_in = x_pad[src_token].reshape(E, C, D)
+    expert_in = x_pad[src_token].reshape(H, C, D)
     g = jax.nn.silu(jnp.einsum("ecd,edf->ecf", expert_in, p["wi_gate"]))
     u = jnp.einsum("ecd,edf->ecf", expert_in, p["wi_up"])
-    expert_out = jnp.einsum("ecf,efd->ecd", g * u, p["wo"]).reshape(E * C, D)
+    expert_out = jnp.einsum("ecf,efd->ecd", g * u, p["wo"]).reshape(H * C, D)
 
     out_pad = jnp.concatenate([expert_out, jnp.zeros((1, D), expert_out.dtype)])
     gathered = out_pad[slot_of]                                    # [T, k, D]

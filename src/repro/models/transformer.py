@@ -3,7 +3,9 @@
 The trunk is ``cfg.num_blocks`` repeats of a ``cfg.block_period``-layer block
 pattern; block parameters are stacked on a leading axis and the forward pass
 ``lax.scan``s over them (compile time stays O(block), roofline extrapolates
-trip counts — see DESIGN.md §9).
+trip counts).  Leading dense layers (``cfg.first_k_dense``) are a segment
+of their own (``params["dense_blocks"]``), scanned before the main stack
+(``cfg.segments``).
 """
 from __future__ import annotations
 
@@ -44,8 +46,7 @@ def make_layer_params(rng, cfg: ModelConfig, kind: dict) -> dict:
     return p
 
 
-def make_block_params(rng, cfg: ModelConfig) -> dict:
-    pattern = cfg.block_pattern()
+def make_block_params(rng, cfg: ModelConfig, pattern: list[dict]) -> dict:
     ks = jax.random.split(rng, len(pattern))
     return {"layers": [make_layer_params(k, cfg, kind)
                        for k, kind in zip(ks, pattern)]}
@@ -53,14 +54,15 @@ def make_block_params(rng, cfg: ModelConfig) -> dict:
 
 def init_params(rng, cfg: ModelConfig) -> dict:
     ks = jax.random.split(rng, 4)
-    block_keys = jax.random.split(ks[0], cfg.num_blocks)
-    blocks = jax.vmap(lambda k: make_block_params(k, cfg))(block_keys)
     params = {
         "embed": layers.make_embed_params(ks[1], cfg),
-        "blocks": blocks,
         "final_norm": layers.make_norm_params(cfg, cfg.d_model),
         "head": layers.make_head_params(ks[2], cfg),
     }
+    for key, pattern, n, _ in cfg.segments():
+        block_keys = jax.random.split(ks[0] if key == "blocks" else ks[3], n)
+        params[key] = jax.vmap(
+            lambda k, pt=pattern: make_block_params(k, cfg, pt))(block_keys)
     return params
 
 
@@ -119,30 +121,37 @@ def forward(cfg: ModelConfig, params: dict, tokens: jax.Array, *,
         positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
     x = layers.embed_tokens(params["embed"], tokens)
     x = shard(x, "hidden")
-    pattern = cfg.block_pattern()
 
-    def block_fn(carry, bp):
-        x = carry
-        auxes = []
-        for i, kind in enumerate(pattern):
-            layer = partial(apply_layer, cfg, kind)
-            if remat == "full" and len(pattern) > 1 and not collect_kv:
-                # heterogeneous blocks (jamba: 8 layers): nested per-layer
-                # remat keeps backward peak at ONE layer's internals
-                layer = jax.checkpoint(layer, prevent_cse=False,
-                                       static_argnums=(3, 4))
-            x, aux = layer(bp["layers"][i], x, positions, collect_kv, shard)
-            auxes.append(aux)
-        return x, (auxes if collect_kv else None)
+    def make_block_fn(pattern):
+        def block_fn(carry, bp):
+            x = carry
+            auxes = []
+            for i, kind in enumerate(pattern):
+                layer = partial(apply_layer, cfg, kind)
+                if remat == "full" and len(pattern) > 1 and not collect_kv:
+                    # heterogeneous blocks (jamba: 8 layers): nested per-layer
+                    # remat keeps backward peak at ONE layer's internals
+                    layer = jax.checkpoint(layer, prevent_cse=False,
+                                           static_argnums=(3, 4))
+                x, aux = layer(bp["layers"][i], x, positions, collect_kv, shard)
+                auxes.append(aux)
+            return x, (auxes if collect_kv else None)
 
-    if remat == "full":
-        block_fn = jax.checkpoint(block_fn, prevent_cse=False)
-    elif remat == "dots":
-        block_fn = jax.checkpoint(
-            block_fn, prevent_cse=False,
-            policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
+        if remat == "full":
+            return jax.checkpoint(block_fn, prevent_cse=False)
+        if remat == "dots":
+            return jax.checkpoint(
+                block_fn, prevent_cse=False,
+                policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
+        return block_fn
 
-    x, caches = jax.lax.scan(block_fn, x, params["blocks"])
+    seg_caches = []
+    for key, pattern, _, _ in cfg.segments():
+        x, c = jax.lax.scan(make_block_fn(pattern), x, params[key])
+        seg_caches.append(c)
+    # caches of all segments on one leading block axis, as the pools hold them
+    caches = (seg_caches[0] if len(seg_caches) == 1 else
+              jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *seg_caches))
     x = layers.apply_norm(cfg, params["final_norm"], x)
     logits = layers.apply_head(cfg, params["head"], params["embed"], x)
     return shard(logits, "logits"), caches

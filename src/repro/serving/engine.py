@@ -375,6 +375,17 @@ class NanoCPEngine:
     def _now(self) -> float:
         return time.monotonic() - self._t0
 
+    def expert_load(self) -> np.ndarray | None:
+        """Routed assignments of live tokens that reached each held expert,
+        summed over MoE layers, decode steps and instances ([held] int64);
+        the decode step counts them on the device and this reads them on
+        demand.  None unless the configuration holds a share of its
+        experts (``cfg.holds_share``)."""
+        load = self.state.get("expert_load")
+        if load is None:
+            return None
+        return np.asarray(jax.device_get(load), np.int64).sum(axis=0)
+
     def add_request(self, prompt_tokens, max_new_tokens: int,
                     now: float | None = None) -> int:
         now = self._now() if now is None else now

@@ -92,7 +92,8 @@ def test_moe_chunked_matches_unchunked(rng, chunk):
 def test_param_counts_match_published():
     expect = {"tinyllama-1.1b": 1.10e9, "qwen2.5-14b": 14.8e9,
               "minicpm3-4b": 4.26e9, "phi3.5-moe-42b-a6.6b": 41.9e9,
-              "mamba2-370m": 0.37e9, "jamba-v0.1-52b": 51.5e9}
+              "mamba2-370m": 0.37e9, "jamba-v0.1-52b": 51.5e9,
+              "deepseek-v3": 671e9}
     for arch, n in expect.items():
         got = CONFIGS[arch].param_counts()["total"]
         assert abs(got - n) / n < 0.05, (arch, got, n)

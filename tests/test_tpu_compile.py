@@ -75,10 +75,14 @@ def _kernel_operand_layouts(hlo: str, lead: int) -> list[str]:
     # the chip cells' decode buckets: f32 pools, ~1024 page slots a row
     (32, 8, 128, 128, jnp.float32, False, 8, 1024, 8193),   # phi35moe.longdecode.1c
     (40, 1, 288, 256, jnp.float32, True, 4, 1024, 3841),    # minicpm3.longdecode.1c
+    # DeepSeek-V3: 128 heads over a 512 + 64 = 576-lane latent (640 in HBM)
+    (128, 1, 576, 512, jnp.bfloat16, True, N, MB, P),
+    (128, 1, 576, 512, jnp.float32, True, 8, 1175, 8193),  # deepseekv3.longdecode.1c
 ], ids=["phi3.5-gqa-bf16", "phi3.5-gqa-f32", "minicpm3-mla-bf16",
         "gqa-int8", "gqa-fp8", "minicpm3-mla-f32-shared",
         "minicpm3-mla-int8-shared", "phi3.5-cell-f32-mb1024",
-        "minicpm3-cell-f32-shared-mb1024"])
+        "minicpm3-cell-f32-shared-mb1024", "deepseekv3-mla-bf16-shared",
+        "deepseekv3-cell-f32-shared-mb1175"])
 def test_paged_decode_compiles_for_v5e(one_chip, Hq, Hkv, Dk, Dv, kv_dtype,
                                        shared_v, n, mb, p):
     quantized = kv_dtype not in (jnp.bfloat16, jnp.float32)
@@ -107,7 +111,8 @@ def test_paged_decode_compiles_for_v5e(one_chip, Hq, Hkv, Dk, Dv, kv_dtype,
 @pytest.mark.parametrize("Hq,Hkv,Dk,Dv,shared_v,n,p", [
     (40, 1, 288, 256, True, 4, 3841),      # minicpm3.longdecode.1c
     (32, 8, 128, 128, False, 8, 8193),     # phi35moe.longdecode.1c
-], ids=["minicpm3-cell", "phi3.5-cell"])
+    (128, 1, 576, 512, True, 8, 8193),     # deepseekv3.longdecode.1c
+], ids=["minicpm3-cell", "phi3.5-cell", "deepseekv3-cell"])
 def test_paged_decode_adds_no_pool_copy_in_the_step(one_chip, Hq, Hkv, Dk,
                                                     Dv, shared_v, n, p):
     """The decode step's shape around the kernel: each layer's f32 pool is
@@ -168,3 +173,56 @@ def test_flash_prefill_compiles_for_v5e_at_any_length(one_chip, monkeypatch):
                         _sds(one_chip, (1, S, 8, 128), jnp.bfloat16),
                         _sds(one_chip, (1, S, 8, 128), jnp.bfloat16))
     assert "tpu_custom_call" in hlo
+
+
+def test_deepseek_v3_share_decode_step_compiles_for_v5e(one_chip):
+    """DeepSeek-V3 at one chip's share (1 dense + 4 MoE layers, 128 heads
+    over a 576-lane latent, 8 of 256 experts, the cell's 131072-token f32
+    pool): the serve step lowers and compiles for a v5e with the paged
+    kernel in each of the trunk's two segments, the pool stays one buffer
+    carried in place (aliased to the step's output), and the kernel adds
+    no copy of a layer's pool.  (The stacked state's layout copies at the
+    step's entry and exit belong to the pool carry, as in MiniCPM3's
+    step.)"""
+    import dataclasses
+    from repro import compat
+    from repro.configs import CONFIGS
+    from repro.core import dcp
+    from repro.models import transformer
+    cfg = dataclasses.replace(CONFIGS["deepseek-v3"], num_layers=5,
+                              first_k_dense=1, held_experts=8,
+                              vocab_size=16160, capacity_factor=32.0)
+    dev = next(iter(one_chip.device_set))
+    mesh = compat.make_mesh((1, 1), ("data", "model"), devices=[dev])
+    M, MB, frames = 8, 1175, 131072 // 16 + 1
+    d = dcp.DecodeDims(M=M, S=0, N=M, MB=MB, W=1, num_frames=frames,
+                       page=16, data_size=1, tp=1, rounds_used=0)
+    params = jax.eval_shape(lambda: dcp.to_decode_params(
+        cfg, transformer.init_params(jax.random.PRNGKey(0), cfg), 1))
+    state = jax.eval_shape(lambda: dcp.init_serve_state(
+        cfg, d, 1, dtype=jnp.float32))
+    shapes = {"slot_rid": (1, M), "slot_token": (1, M), "slot_pos": (1, M),
+              "slot_active": (1, M), "append_frame": (1, M),
+              "append_off": (1, M), "q_send_idx": (1, 0, 0),
+              "q_recv_slot": (1, 0, 0), "work_src": (1, M),
+              "work_bt": (1, M, MB), "work_len": (1, M),
+              "ret_send_idx": (1, 0, 0), "merge_src": (1, M, 1),
+              "merge_round": (1, M, 1), "merge_peer_row": (1, M, 1)}
+    tables = {k: jax.ShapeDtypeStruct(v, jnp.int32)
+              for k, v in shapes.items()}
+    ops_force = ops.FORCE_IMPL
+    ops.FORCE_IMPL = "pallas"
+    try:
+        fn = dcp.make_serve_step(cfg, d, mesh, params, state, tables)
+        compiled = fn.lower(params, state, tables).compile()
+    finally:
+        ops.FORCE_IMPL = ops_force
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 2       # dense segment, MoE segment
+    assert state["kv_pool"].shape == (5, 1, 1, 1, frames, 16, 576)
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        state["kv_pool"].size * 4
+    copies = [l for l in hlo.splitlines()
+              if re.search(rf"= \(?\w+\[{frames},", l)
+              and re.search(r"\bcopy(-start)?\(", l)]
+    assert not copies, copies[:2]
