@@ -38,6 +38,7 @@ from jax.sharding import PartitionSpec as P
 from ..compat import shard_map as _shard_map
 from ..configs.base import ModelConfig
 from ..kernels import ops, quant, ref
+from ..kernels.paged_attention import lane_tiles
 from ..models import layers as L
 from . import comm
 from .moe_parallel import dense_decode_ffn, moe_decode_ffn
@@ -297,9 +298,13 @@ def init_serve_state(cfg: ModelConfig, dims: DecodeDims, num_instances: int,
         pdt = quant.kv_storage_dtype(dims.kv_dtype, dtype)
         sc_shape = (nb, n_attn, I, dims.tp, fp)
         if cfg.is_mla:
-            dk = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+            # the latent (kv_lora_rank + rope lanes) is stored at whole
+            # 128-lane tiles, its pad lanes zero and never read: at 288 or
+            # 576 lanes the TPU would lay the state out frame-minor and
+            # relayout it for the paged kernel, which reads page-major tiles
+            lanes = lane_tiles(cfg.kv_lora_rank + cfg.qk_rope_head_dim)
             state["kv_pool"] = jnp.zeros(
-                (nb, n_attn, I, dims.tp, fp, dims.page, dk), pdt)
+                (nb, n_attn, I, dims.tp, fp, dims.page, lanes), pdt)
             if quant.is_quantized(dims.kv_dtype):
                 state["kv_scale"] = jnp.ones(sc_shape, jnp.float32)
         else:
@@ -395,14 +400,62 @@ def _split_pages(bt, length, ps, p_j, mbt, page):
     return bt_local.astype(bt.dtype), jnp.sum(toks_sel, axis=1).astype(length.dtype)
 
 
+def _append_kv(dims: DecodeDims, tbl, pools, new_k, new_v, *, ps, p_j,
+               base, frames):
+    """KV append (write-then-attend) of this step's M tokens: the only pool
+    bytes the decode step writes.
+
+    Only the frame's stripe owner writes; everyone else (and inactive
+    slots) scatters into the layer's scratch frame (its last frame, never
+    handed out by the allocator).  pools = (k_pool, v_pool, k_scale,
+    v_scale) as ``_dcp_attention`` takes them, the layer's frames starting
+    at ``base``; a row fills the first lanes of the pool's (a padded
+    latent's pad lanes stay zero)."""
+    k_pool, v_pool, k_scale, v_scale = pools
+    M, page = dims.M, k_pool.shape[1]
+    act = tbl["slot_active"][0].astype(bool)
+    af_g = tbl["append_frame"][0]
+    mine = act & ((af_g % ps) == p_j) if ps > 1 else act
+    af = base + jnp.where(mine, af_g // ps, frames - 1)            # [M]
+    ao = jnp.where(mine, tbl["append_off"][0], jnp.arange(M) % page)
+    kl = new_k.shape[-1]
+    if k_scale is None:
+        k_pool = k_pool.at[af, ao, :kl].set(new_k.astype(k_pool.dtype))
+        if v_pool is not None:
+            v_pool = v_pool.at[af, ao].set(new_v.astype(v_pool.dtype))
+        return k_pool, v_pool, k_scale, v_scale
+    # offset-0 rule: an append landing at page offset 0 starts a fresh
+    # page, so it RESETS that page's scale to this token's amax/qmax;
+    # appends at later offsets CLIP into the page's existing scale
+    # (already-stored tokens are never re-scaled).  Distinct active slots
+    # never share an append frame; duplicate scatter rows only hit the
+    # scratch frame (garbage anyway).
+    ks_eff = jnp.where(ao == 0, quant.amax_scale(new_k, dims.kv_dtype),
+                       k_scale[af])
+    k_pool = k_pool.at[af, ao, :kl].set(
+        quant.quantize(new_k, ks_eff[:, None], dims.kv_dtype))
+    k_scale = k_scale.at[af].set(ks_eff)
+    if v_pool is not None:
+        vs_eff = jnp.where(ao == 0, quant.amax_scale(new_v, dims.kv_dtype),
+                           v_scale[af])
+        v_pool = v_pool.at[af, ao].set(
+            quant.quantize(new_v, vs_eff[:, None], dims.kv_dtype))
+        v_scale = v_scale.at[af].set(vs_eff)
+    return k_pool, v_pool, k_scale, v_scale
+
+
 def _dcp_attention(cfg, dims: DecodeDims, q, k_pool, v_pool, new_k, new_v,
-                   tbl, *, dk, dv, geom, k_scale=None, v_scale=None):
+                   tbl, *, dk, dv, geom, k_scale=None, v_scale=None,
+                   base=0, frames=None):
     """Phases 1-4 for one attention layer (per device).
 
     q: [M, hl, dk] local-slot queries.  k_pool/v_pool: [F', page, kg*(dk|dv)]
     — the device's hybrid-sharded sub-pool: kv-head group h_j = chunk % khs
     (kg = Hkv/khs heads per group, flattened into the last dim), page
-    stripe p_j = chunk // khs (geom = (hp, khs, ps); DESIGN.md §2).
+    stripe p_j = chunk // khs (geom = (hp, khs, ps); DESIGN.md §2).  MLA's
+    latent pool is stored at whole 128-lane tiles past dk.  The decode
+    step passes every layer's frames as one pool: this layer's ``frames``
+    (F') frames start at frame ``base``.
     new_k/new_v: [M, kg*(dk|dv)] this step's token KV for the device's kv
     heads (written at append_frame/off iff the frame's stripe is p_j), or
     new_k=None for read-only pools (whisper cross-attention).
@@ -416,47 +469,19 @@ def _dcp_attention(cfg, dims: DecodeDims, q, k_pool, v_pool, new_k, new_v,
     R = dims.num_rounds
     hp, khs, ps = geom
     hl = hp // dims.tp
-    Fp, page = k_pool.shape[0], k_pool.shape[1]
-    kg = k_pool.shape[-1] // dk                     # kv heads per model chunk
+    Fp = frames or k_pool.shape[0]
+    page = k_pool.shape[1]
+    kg = kv_group_size(cfg, dims.tp)                # kv heads per model chunk
     assert kg == 1 or ps == 1, (kg, ps)
     j = jax.lax.axis_index(dims.model)
     p_j = j // khs
     groups = [[p * khs + h for p in range(ps)] for h in range(khs)]
 
     if new_k is not None:
-        # -- KV append (write-then-attend) --
-        # Only the frame's stripe owner writes; everyone else (and inactive
-        # slots) scatters into the local scratch frame (last frame of the
-        # sub-pool, never handed out by the allocator).
-        act = tbl["slot_active"][0].astype(bool)
-        af_g = tbl["append_frame"][0]
-        mine = act & ((af_g % ps) == p_j) if ps > 1 else act
-        af = jnp.where(mine, af_g // ps, Fp - 1)               # [M]
-        ao = jnp.where(mine, tbl["append_off"][0], jnp.arange(M) % page)
-        if k_scale is None:
-            k_pool = k_pool.at[af, ao].set(new_k.astype(k_pool.dtype))
-            if v_pool is not None:
-                v_pool = v_pool.at[af, ao].set(new_v.astype(v_pool.dtype))
-        else:
-            # offset-0 rule: an append landing at page offset 0 starts a
-            # fresh page, so it RESETS that page's scale to this token's
-            # amax/qmax; appends at later offsets CLIP into the page's
-            # existing scale (already-stored tokens are never re-scaled).
-            # Distinct active slots never share an append frame; duplicate
-            # scatter rows only hit the scratch frame (garbage anyway).
-            ks_eff = jnp.where(ao == 0,
-                               quant.amax_scale(new_k, dims.kv_dtype),
-                               k_scale[af])
-            k_pool = k_pool.at[af, ao].set(
-                quant.quantize(new_k, ks_eff[:, None], dims.kv_dtype))
-            k_scale = k_scale.at[af].set(ks_eff)
-            if v_pool is not None:
-                vs_eff = jnp.where(ao == 0,
-                                   quant.amax_scale(new_v, dims.kv_dtype),
-                                   v_scale[af])
-                v_pool = v_pool.at[af, ao].set(
-                    quant.quantize(new_v, vs_eff[:, None], dims.kv_dtype))
-                v_scale = v_scale.at[af].set(vs_eff)
+        with jax.named_scope("pool_carry"):
+            k_pool, v_pool, k_scale, v_scale = _append_kv(
+                dims, tbl, (k_pool, v_pool, k_scale, v_scale), new_k, new_v,
+                ps=ps, p_j=p_j, base=base, frames=Fp)
 
     # -- Phase 1: Q-routing --
     if dims.backend == "dense" and R > 0:
@@ -494,11 +519,13 @@ def _dcp_attention(cfg, dims: DecodeDims, q, k_pool, v_pool, new_k, new_v,
                                        ps, p_j, dims.MBT or dims.MB, dims.page)
     else:
         bt_dev, len_dev = tbl["work_bt"][0], tbl["work_len"][0]
-    kp = k_pool.reshape(Fp, page, kg, dk)                          # [F',page,kg,dk]
+    # the kernel reads the layer's pages where they lie in the whole pool
+    P = k_pool.shape[0]
+    kp = k_pool.reshape(P, page, kg, -1)                           # [P,page,kg,dk]
     # MLA passes no V pool: the kernel reads V from the latent it holds
-    vp = v_pool.reshape(Fp, page, kg, dv) if v_pool is not None else None
+    vp = v_pool.reshape(P, page, kg, dv) if v_pool is not None else None
     out, lse = ops.paged_decode_attention(
-        q_work, kp, vp, bt_dev, len_dev,
+        q_work, kp, vp, bt_dev + base, len_dev,
         scale=dk ** -0.5 if cfg.attention != "mla" else
         cfg.mla_softmax_scale,
         # fused dequant: per-page scales follow the same local frame ids as
@@ -554,11 +581,13 @@ def _dcp_attention(cfg, dims: DecodeDims, q, k_pool, v_pool, new_k, new_v,
     return merged, k_pool, v_pool, k_scale, v_scale
 
 
-def _attn_layer(cfg, dims, lp, x, pos, pools, tbl, hl, geom):
+def _attn_layer(cfg, dims, lp, x, pos, pools, tbl, hl, geom, base, frames):
     """One GQA/MLA attention layer (per device).
 
-    pools = (k_pool, v_pool, k_scale, v_scale); the scale entries are None
-    for bf16 pools (MLA: (kv_pool, None, kv_scale, None)).
+    pools = (k_pool, v_pool, k_scale, v_scale), every layer's frames in
+    one run, this layer's ``frames`` of them from frame ``base``; the
+    scale entries are None for bf16 pools (MLA: (kv_pool, None, kv_scale,
+    None)).
     """
     hd = cfg.head_dim_
     h = L.apply_norm(cfg, lp["ln1"], x)
@@ -585,7 +614,8 @@ def _attn_layer(cfg, dims, lp, x, pos, pools, tbl, hl, geom):
         merged, kp, _, ksc, _ = _dcp_attention(cfg, dims, q, pools[0], None,
                                                new_k, None, tbl, dk=kvr + dr,
                                                dv=kvr, geom=geom,
-                                               k_scale=pools[2])
+                                               k_scale=pools[2], base=base,
+                                               frames=frames)
         o = jnp.einsum("mhk,hkd->mhd", merged, mx["wv_b"])         # [M,hl,dv]
         o = o.reshape(M, hl * dv) @ lp["mixer"]["wo"]
         return jax.lax.psum(o, dims.model), (kp, None, ksc, None)
@@ -609,7 +639,8 @@ def _attn_layer(cfg, dims, lp, x, pos, pools, tbl, hl, geom):
     merged, kp, vp, ksc, vsc = _dcp_attention(cfg, dims, q, pools[0], pools[1],
                                               k, v, tbl, dk=hd, dv=hd,
                                               geom=geom, k_scale=pools[2],
-                                              v_scale=pools[3])
+                                              v_scale=pools[3], base=base,
+                                              frames=frames)
     o = merged.reshape(M, hl * hd) @ mx["wo"]
     return jax.lax.psum(o, dims.model), (kp, vp, ksc, vsc)
 
@@ -668,7 +699,10 @@ def build_decode_step(cfg: ModelConfig, dims: DecodeDims):
     hp = geom[0]
     hl = hp // dims.tp if hp else 0
     vs_local = cfg.padded_vocab // dims.tp
-    quantized = quant.is_quantized(dims.kv_dtype)
+    n_attn = sum(1 for k in cfg.block_pattern() if k["mixer"] == "attn")
+    # the state's pools in ``_attn_layer``'s (k, v, k_scale, v_scale) order
+    pool_keys = (("kv_pool", None, "kv_scale", None) if cfg.is_mla else
+                 ("k_pool", "v_pool", "k_scale", "v_scale"))
 
     def step(params, state, tbl):
         tokens = tbl["slot_token"][0]                              # [M]
@@ -677,18 +711,28 @@ def build_decode_step(cfg: ModelConfig, dims: DecodeDims):
         x = _embed_lookup(params["embed"]["tok"], tokens, vs_local, dims.model)
         x = x.astype(params["embed"]["tok"].dtype)   # carry dtype = param dtype
 
-        # KV pools / SSM states travel as scan CARRY with per-block
-        # dynamic-slice/update, so XLA's loop aliasing keeps ONE in-place
-        # buffer (scan xs/ys would double-buffer them; measured 3.6x pool
-        # bytes of temp on the 14B decode cell).  Each segment of the trunk
-        # (``cfg.segments``: leading dense layers, then the main stack) is
-        # one scan over its blocks, carrying the same pools.
+        # The attention pools are carried IN PLACE: each is viewed as one
+        # run of frames [nb*n_attn*F', page, lanes] (a reshape of the
+        # device's [nb, n_attn, 1, 1, F', ...] block, a bitcast in the
+        # tiled layout; scale sidecars [nb*n_attn*F']).  Layer (i, ai)
+        # owns frames from (i*n_attn + ai)*F': the append writes its M new
+        # rows there, and the kernel reads the layer's pages through block
+        # tables offset by that much, so no step slices, writes back or
+        # relays out a pool.  SSM states (small, per slot) travel as scan
+        # carry with per-block dynamic-slice/update.  Each segment of the
+        # trunk (``cfg.segments``: leading dense layers, then the main
+        # stack) is one scan over its blocks, carrying the same buffers.
         state = dict(state)
         load = state.pop("expert_load", None)
         live = None if load is None else tbl["slot_active"][0]
+        shapes = {k: state[k].shape for k in pool_keys if k in state}
+        with jax.named_scope("pool_carry"):
+            pools = tuple(state.pop(k).reshape((-1,) + shapes[k][5:])
+                          if k in shapes else None for k in pool_keys)
+        frames = next(iter(shapes.values()))[4] if shapes else 0
 
         def block_fn(pattern, carry, xs):
-            x, st, load = carry
+            x, pools, st, load = carry
             i, bp = xs["idx"], xs["params"]
             # fp8-stored weights dequantise at use (in-register on TPU; the
             # param stream is charged at fp8 width)
@@ -707,40 +751,10 @@ def build_decode_step(cfg: ModelConfig, dims: DecodeDims):
             for li, kind in enumerate(pattern):
                 lp = bp["layers"][li]
                 if kind["mixer"] == "attn":
-                    # per-device sub-pool: [ai, I=0, tp=0, F', page, dk]
-                    # (scale sidecars [ai, I=0, tp=0, F'] when quantized)
-                    with jax.named_scope("pool_carry"):
-                        if cfg.is_mla:
-                            pools = (blk["kv_pool"][ai, 0, 0], None,
-                                     blk["kv_scale"][ai, 0, 0] if quantized
-                                     else None, None)
-                        else:
-                            pools = (blk["k_pool"][ai, 0, 0],
-                                     blk["v_pool"][ai, 0, 0],
-                                     blk["k_scale"][ai, 0, 0] if quantized
-                                     else None,
-                                     blk["v_scale"][ai, 0, 0] if quantized
-                                     else None)
                     with jax.named_scope("attention"):
-                        mix, pools_out = _attn_layer(cfg, dims, lp, x, pos,
-                                                     pools, tbl, hl, geom)
-                    with jax.named_scope("pool_carry"):
-                        if cfg.is_mla:
-                            upd.setdefault("kv_pool", []).append(
-                                pools_out[0][None])
-                            if quantized:
-                                upd.setdefault("kv_scale", []).append(
-                                    pools_out[2][None])
-                        else:
-                            upd.setdefault("k_pool", []).append(
-                                pools_out[0][None])
-                            upd.setdefault("v_pool", []).append(
-                                pools_out[1][None])
-                            if quantized:
-                                upd.setdefault("k_scale", []).append(
-                                    pools_out[2][None])
-                                upd.setdefault("v_scale", []).append(
-                                    pools_out[3][None])
+                        mix, pools = _attn_layer(
+                            cfg, dims, lp, x, pos, pools, tbl, hl, geom,
+                            base=(i * n_attn + ai) * frames, frames=frames)
                     ai += 1
                 else:
                     with jax.named_scope("pool_carry"):
@@ -770,18 +784,21 @@ def build_decode_step(cfg: ModelConfig, dims: DecodeDims):
                                                  tp_axis=dims.model)
                     x = x + f
             with jax.named_scope("pool_carry"):
-                blk_new = {k: jnp.stack(v)[:, None] for k, v in upd.items()}
                 st = {k: jax.lax.dynamic_update_index_in_dim(
-                    st[k], blk_new[k], i, 0) for k in st}
-            return (x, st, load), None
+                    st[k], jnp.stack(upd[k])[:, None], i, 0) for k in st}
+            return (x, pools, st, load), None
 
-        carry = (x, state, load)
+        carry = (x, pools, state, load)
         for key, pattern, n, first in cfg.segments():
             xs = {"params": params[key], "idx": jnp.arange(first, first + n)}
             carry, _ = jax.lax.scan(partial(block_fn, pattern), carry, xs)
-        x, new_pools, load = carry
+        x, pools, new_state, load = carry
+        with jax.named_scope("pool_carry"):
+            for k, pool in zip(pool_keys, pools):
+                if pool is not None:
+                    new_state[k] = pool.reshape(shapes[k])
         if load is not None:
-            new_pools["expert_load"] = load
+            new_state["expert_load"] = load
 
         with jax.named_scope("head"):
             x = L.apply_norm(cfg, params["final_norm"], x)
@@ -792,7 +809,7 @@ def build_decode_step(cfg: ModelConfig, dims: DecodeDims):
             logits = logits.astype(jnp.float32)
             nxt = _sample_greedy(logits, vs_local, dims.model)
             nxt = jnp.where(tbl["slot_active"][0].astype(bool), nxt, -1)
-        return new_pools, nxt[None, :], logits[None]
+        return new_state, nxt[None, :], logits[None]
 
     return step
 

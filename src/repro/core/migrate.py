@@ -68,12 +68,16 @@ def load_prefill_kv(cfg: ModelConfig, cluster: ClusterState, dims: DecodeDims,
             c_kv, k_rope = kv
             lat = np.concatenate([np.asarray(c_kv, np.float32),
                                   np.asarray(k_rope, np.float32)], axis=-1)
-            pool = state_np["kv_pool"]            # [nb, na, I, tp, F', page, dk]
+            # [nb, na, I, tp, F', page, lanes]: the latent fills the first
+            # dk lanes of a row stored at whole 128-lane tiles
+            pool = state_np["kv_pool"]
+            dk = lat.shape[-1]
             for s, start, t in ranges:
                 frames = pt.shard_frames(rid, s)
                 for j in range(t):
                     f, o = frames[j // page], j % page
-                    pool[bi, pos, s, (f % ps) * khs, f // ps, o] = lat[start + j]
+                    pool[bi, pos, s, (f % ps) * khs, f // ps, o, :dk] = \
+                        lat[start + j]
         else:
             k, v = kv
             k = np.asarray(k, np.float32)
@@ -218,7 +222,7 @@ class PrefillScatter:
         sc_new = jnp.where(has0[None, None],
                            jnp.maximum(fresh, quant.SCALE_FLOOR), sc)
         s_eff = sc_new[:, :, ii, c, ff]                      # [nb,na,T,khs]
-        pool_new = pool.at[:, :, ii, c, ff, oo].set(
+        pool_new = pool.at[:, :, ii, c, ff, oo, :x.shape[-1]].set(
             quant.quantize(x, s_eff[..., None], kv_dtype), mode="drop")
         return pool_new, sc_new
 
@@ -236,7 +240,9 @@ class PrefillScatter:
                 state["kv_pool"], state["kv_scale"] = self._quantized_scatter(
                     kp, state["kv_scale"], k, ii, c, ff, oo, off)
             else:
-                state["kv_pool"] = kp.at[:, :, ii, c, ff, oo].set(
+                # the first dk lanes of the latent's whole-tile rows
+                state["kv_pool"] = kp.at[:, :, ii, c, ff, oo,
+                                         :k.shape[-1]].set(
                     k.astype(kp.dtype), mode="drop")
         else:
             kp, vp = state["k_pool"], state["v_pool"]
@@ -388,6 +394,7 @@ class KVReshard:
             keys = ("kv_pool",) if self.sc.cfg.is_mla else ("k_pool", "v_pool")
             for key in keys:
                 p = state[key]
+                # whole rows: a padded latent's zero pad lanes move with it
                 vals = p[:, :, si, c_s, fs, so]      # [nb, na, T, khs, d]
                 state[key] = p.at[:, :, di, c_d, fd, do].set(vals, mode="drop")
             return state

@@ -102,7 +102,10 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
 
     q [N, Hq, Dk]; pages [P, page, Hkv, D] (per-device sub-pool view: the
     stripe (ps) dim is resolved by the caller's frame indices, the group
-    (kg) dim is the Hkv axis).  ``v_pages=None`` means V is the first
+    (kg) dim is the Hkv axis; a one-head pool may be stored past Dk, at
+    whole 128-lane tiles, and its pad lanes are never read).  In the decode
+    step P spans every layer's frames and the block tables hold the
+    layer's frame offset.  ``v_pages=None`` means V is the first
     ``v_dim`` lanes of each K head (MLA's shared latent pool), read once
     per page.  Quantized (fp8/int8) pools additionally pass per-page
     ``k_scale`` and, with a V pool, ``v_scale`` [P] f32 — dequant is fused

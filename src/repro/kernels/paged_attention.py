@@ -9,17 +9,20 @@ TPU mapping:
     ``ppb`` consecutive pages of one row (``pages_per_block`` picks ppb
     from the page size, lane width and MB).
   * the pools are passed as [P, page, Hkv*D], their own flattened
-    layout, in whatever memory XLA holds them (``pl.ANY``).  In the decode
-    step a layer's pool is the pool carry's per-layer slice, which XLA
-    writes to VMEM where it fits (MiniCPM3's 94-MB latent) and to HBM
-    otherwise; a pin to HBM only adds a copy of the whole pool per layer
-    (``tests/test_tpu_compile.py`` checks that the step adds none).  Each
-    block's pages are gathered by the scalar-prefetched block table, one
-    ``make_async_copy`` per page, into a VMEM buffer
-    [2, ppb, page, lanes] (K, and V unless V is shared), ``lanes`` the
-    pool's width in whole 128-lane tiles (``_lane_tiles``).  In interpret
-    mode the pools are padded to that width with junk, so the CPU runs the
-    same copies and buffers as the chip.
+    layout, where XLA holds them (``pl.ANY``).  In the decode step that is
+    the whole stacked pool of every layer, in HBM, viewed as one run of
+    frames (``core/dcp.py`` ``build_decode_step``): the caller adds the
+    layer's first frame to its block table, so the kernel reads each
+    layer's pages where they lie and no per-layer pool is ever sliced out
+    (``tests/test_tpu_compile.py`` checks that the step has no pool-sized
+    op but the append).  Each block's pages are gathered by the
+    scalar-prefetched block table, one ``make_async_copy`` per page, into
+    a VMEM buffer [2, ppb, page, lanes] (K, and V unless V is shared),
+    ``lanes`` the pool's width in whole 128-lane tiles (``lane_tiles``).
+    A one-head pool may be stored at that width already (MLA's 576-lane
+    latent at 640: ``init_serve_state``), its pad lanes never computed on.
+    In interpret mode a narrower pool is padded to that width with junk,
+    so the CPU runs the same copies and buffers as the chip.
   * double buffering: a block's copies are started one block ahead, into
     the other half of the buffer — the row's next block, or the first
     block of the next row with a nonzero length — before the current
@@ -79,7 +82,7 @@ def pages_per_block(page: int, lanes: int, num_pages: int) -> int:
     return min(1 << (ppb.bit_length() - 1), num_pages)
 
 
-def _lane_tiles(lanes: int) -> int:
+def lane_tiles(lanes: int) -> int:
     """Lanes of a pool's page row as the TPU lays it out in memory: whole
     128-lane tiles (every TPU tiling of a 2-D minor block has 128 lanes,
     ``tests/test_tpu_compile.py`` checks the pools' compiled layout).
@@ -141,7 +144,7 @@ def _kernel(
             frame = block_tables_ref[row, blk * ppb + j]
             for pool, buf, sem in pools:
                 # whole 128-lane tiles: past the pool's last lane this
-                # reads its tile padding (``_lane_tiles``), never used
+                # reads its tile padding (``lane_tiles``), never used
                 src = pool.at[frame, :, pl.ds(0, buf.shape[-1])]
                 copy = pltpu.make_async_copy(src, buf.at[slot, j],
                                              sem.at[slot])
@@ -245,7 +248,9 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
     q [N, Hq, Dk]; k_pages [P, page, Hkv, Dk]; v_pages [P, page, Hkv, Dv],
     or None when V is the first ``v_dim`` lanes of each K head (MLA's
     shared latent: one DMA per page); block_tables [N, MB] int32 (entries
-    at or past a row's length may hold any id); lengths [N] int32.
+    at or past a row's length may hold any id); lengths [N] int32.  A
+    one-head pool (Hkv == 1) may be stored at ``lane_tiles(Dk)`` lanes:
+    only its first Dk lanes are computed on.
 
     Quantized pools (fp8/int8, ``kernels/quant.py``): pass per-page
     ``k_scale`` and, with a V pool, ``v_scale`` [P] f32 (a shared V takes
@@ -259,7 +264,8 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
     tests/test_tpu_compile.py.
     """
     N, Hq, Dk = q.shape
-    P, page, Hkv, _ = k_pages.shape
+    P, page, Hkv, Dp = k_pages.shape
+    assert Dp == Dk or (Hkv == 1 and Dp == lane_tiles(Dk)), (Dp, Dk, Hkv)
     shared_v = v_pages is None
     Dv = v_dim if shared_v else v_pages.shape[-1]
     MB = block_tables.shape[1]
@@ -301,16 +307,16 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
     q4 = q.reshape(N, Hkv, G, Dk)            # group q heads by kv head
     # [P, page, Hkv, D] -> [P, page, Hkv*D]: a free reshape (the pools are
     # stored with the heads flattened into the last dim)
-    pools = [k_pages.reshape(P, page, Hkv * Dk)]
+    pools = [k_pages.reshape(P, page, Hkv * Dp)]
     if not shared_v:
         pools.append(v_pages.reshape(P, page, Hkv * Dv))
     if interpret:
-        # stand-in for the tile padding (see ``_lane_tiles``), NaN so a
+        # stand-in for the tile padding (see ``lane_tiles``), NaN so a
         # padding lane that reached the math would show
-        pools = [jnp.pad(p, ((0, 0), (0, 0), (0, _lane_tiles(p.shape[-1])
+        pools = [jnp.pad(p, ((0, 0), (0, 0), (0, lane_tiles(p.shape[-1])
                                                 - p.shape[-1])),
                          constant_values=_junk(p.dtype)) for p in pools]
-    bufs = [pltpu.VMEM((2, ppb, page, _lane_tiles(p.shape[-1])), p.dtype)
+    bufs = [pltpu.VMEM((2, ppb, page, lane_tiles(p.shape[-1])), p.dtype)
             for p in pools]
     sems = [pltpu.SemaphoreType.DMA((2,)) for _ in pools]
 

@@ -134,7 +134,9 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
     """Decode attention over a paged KV pool, with LSE output.
 
     q:            [N, Hq, Dk]      one query token per work row
-    k_pages:      [P, page, Hkv, Dk]
+    k_pages:      [P, page, Hkv, Dk]; a one-head pool may be wider than
+                  Dk (stored at whole 128-lane tiles), and only its first
+                  Dk lanes are read
     v_pages:      [P, page, Hkv, Dv], or None: V is then the first ``v_dim``
                   lanes of each K head (MLA's shared latent pool)
     block_tables: [N, MB] int32    page ids per row (entries >= lengths ignored)
@@ -166,7 +168,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
     # gather pages in their STORED dtype; grouped-head einsums with f32
     # accumulation avoid ever materialising head-expanded / f32 KV copies
     # (this path is what the CPU dry-run lowers — memory must stay honest).
-    k = k_pages[block_tables].reshape(N, MB * page, Hkv, Dk)
+    k = k_pages[block_tables][..., :Dk].reshape(N, MB * page, Hkv, Dk)
     if k_scale is not None:
         # quantized pools: dequant only the gathered [N, MB*page] window.
         # Scales are per page, constant across the page's tokens/head-dims.

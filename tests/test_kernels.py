@@ -9,7 +9,8 @@ import pytest
 from conftest import junk_past_length
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.paged_attention import (BLOCK_BYTES, pages_per_block,
+from repro.kernels.paged_attention import (BLOCK_BYTES, lane_tiles,
+                                           pages_per_block,
                                            paged_decode_attention)
 
 
@@ -131,6 +132,46 @@ def test_paged_decode_grouped_subpool_view(rng, kg, g_out):
         np.testing.assert_allclose(
             np.asarray(l[:, h * g_out:(h + 1) * g_out]), np.asarray(l_r),
             atol=1e-3)
+
+
+@pytest.mark.parametrize("Hq,Dk,Dv,dtype", [
+    (8, 288, 256, jnp.float32),      # MiniCPM3's latent, stored at 384 lanes
+    (16, 576, 512, jnp.bfloat16),    # DeepSeek-V3's, stored at 640
+    (4, 40, 32, jnp.float32),        # the reduced configs' latent, at 128
+    (8, 288, 256, jnp.int8),         # quantized, junk = the int8 maximum
+], ids=["minicpm3-f32", "deepseekv3-bf16", "reduced-f32", "minicpm3-int8"])
+def test_paged_decode_never_reads_pad_lanes(rng, Hq, Dk, Dv, dtype):
+    """A one-head latent pool stored at whole 128-lane tiles
+    (``init_serve_state``), with junk (NaN) in its pad lanes: the kernel
+    (interpret mode) and the oracle both give the unpadded pool's result,
+    so no pad lane reaches the arithmetic."""
+    N, page, P, MB = 3, 16, 32, 8
+    q = jnp.asarray(rng.standard_normal((N, Hq, Dk)), jnp.float32)
+    if dtype == jnp.int8:
+        kp = jnp.asarray(rng.integers(-127, 128, (P, page, 1, Dk)), dtype)
+        junk = 127
+        scales = dict(k_scale=jnp.asarray(
+            rng.uniform(0.005, 0.02, (P,)), jnp.float32))
+    else:
+        kp = jnp.asarray(rng.standard_normal((P, page, 1, Dk)), dtype)
+        junk = jnp.nan
+        scales = {}
+    padded = jnp.pad(kp, ((0, 0), (0, 0), (0, 0), (0, lane_tiles(Dk) - Dk)),
+                     constant_values=junk)
+    bt = jnp.asarray(rng.integers(0, P, (N, MB)), jnp.int32)
+    lengths = jnp.asarray([MB * page, 37, 0], jnp.int32)
+    o_r, l_r = ref.paged_decode_attention(q, kp, None, bt, lengths,
+                                          v_dim=Dv, **scales)
+    o_p, l_p = ref.paged_decode_attention(q, padded, None, bt, lengths,
+                                          v_dim=Dv, **scales)
+    o_k, l_k = paged_decode_attention(q, padded, None, bt, lengths, v_dim=Dv,
+                                      interpret=True, **scales)
+    np.testing.assert_array_equal(np.asarray(o_p), np.asarray(o_r))
+    np.testing.assert_array_equal(np.asarray(l_p), np.asarray(l_r))
+    assert np.all(np.isfinite(np.asarray(o_k)))
+    np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_r), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(l_k)[:2], np.asarray(l_r)[:2],
+                               atol=1e-3)
 
 
 @pytest.mark.parametrize("B,Sq,Skv,Hq,Hkv,D,causal,dtype", [
